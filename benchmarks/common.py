@@ -1,9 +1,10 @@
 """Shared benchmark utilities: timed runs, CSV emission, cached calibration.
 
-Scaling note (DESIGN.md §7): this container is one CPU core; networks are
-scaled (64-512 neurons, 50-250 ms biological time) with the paper's regime
-structure preserved.  Reported quantities are step counts, event counts and
-wall-clock ratios — the same quantities the paper reports.
+Scaling note (DESIGN.md §7): these suites were sized for one CPU core;
+networks are scaled (64-512 neurons, 50-250 ms biological time) with the
+paper's regime structure preserved.  Reported quantities are step counts,
+event counts and wall-clock ratios — the same quantities the paper
+reports.  Their wall times are CPU times, never device metrics.
 """
 from __future__ import annotations
 
@@ -52,6 +53,20 @@ def record_csv(text: str):
             continue
         _RECORDS.append({"name": parts[0], "us_per_call": us,
                          "derived": parts[2]})
+
+
+def cpu_mesh_env(root: str, n_devices: int) -> dict:
+    """Environment for a worker process that measures HLO bytes on an
+    emulated mesh of ``n_devices`` host CPU devices.  ``JAX_PLATFORMS=cpu``
+    keeps the worker off any accelerator: on a TPU host the parent already
+    holds the chip, and the host-platform flag does not hide it."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [root, os.path.join(root, "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    return env
 
 
 def dump_json(suite: str):

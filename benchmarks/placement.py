@@ -40,16 +40,11 @@ P_IN = 0.99          # block wiring: in-block probability of each in-edge
 def run() -> None:
     """Orchestrator entry (run.py / check.sh): spawn the forced-host-device
     worker, stream its CSV through, record it for the JSON dump."""
-    from benchmarks.common import dump_json, record_csv
+    from benchmarks.common import cpu_mesh_env, dump_json, record_csv
 
     quick = os.environ.get("REPRO_BENCH_QUICK") == "1"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                        + ("4" if quick else "256"))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [root, os.path.join(root, "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    env = cpu_mesh_env(root, 4 if quick else 256)
     res = subprocess.run(
         [sys.executable, "-m", "benchmarks.placement", "--worker"],
         env=env, capture_output=True, text=True, cwd=root,
@@ -75,13 +70,17 @@ def _worker() -> None:
     from repro.distributed.exchange import ExchangeSpec
     from repro.distributed.fap_spmd import PaperNeuroSpec, build_fap_round
     from repro.launch.hlo_analysis import collective_channel_bytes
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     quick = os.environ.get("REPRO_BENCH_QUICK") == "1"
     shape = (2, 2) if quick else (16, 16)
     n = 256 if quick else 65536
     k_in = 4 if quick else 16
-    mesh = make_mesh_compat(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
+    # HLO bytes on an emulated CPU mesh, by design (JAX_PLATFORMS=cpu)
+    emit("placement/platform", 0.0,
+         f"platform={jax.devices()[0].platform};count={len(jax.devices())};"
+         "emulated_cpu_mesh=True")
     n_shards = int(np.prod(shape))
     model = CellModel(morphology.soma_only())
 

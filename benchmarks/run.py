@@ -7,6 +7,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import (accuracy_fig5, active_set, delays_fig3,
                             discontinuities_fig7, event_wheel, exchange,

@@ -153,7 +153,7 @@ def reinit(model, t, y, iinj, opts: BDFOptions, counters=None,
     c = counters or (z, z + 1, z, z, z, z, z)
     return BDFState(t=jnp.asarray(t, y.dtype), h=h, q=jnp.ones((), jnp.int32),
                     zn=zn, tau=tau, qwait=jnp.full((), 2, jnp.int32),
-                    etamax=jnp.asarray(ETAMX1), acor_save=jnp.zeros_like(y),
+                    etamax=jnp.asarray(ETAMX1, y.dtype), acor_save=jnp.zeros_like(y),
                     nst=c[0], nfe=c[1], nni=c[2], netf=c[3], nncf=c[4],
                     nreset=c[5], failed=jnp.zeros((), bool),
                     gamma_saved=jnp.ones((), y.dtype),

@@ -204,7 +204,7 @@ def make_spike_insert(net, dnet: DeviceNet, qops, qinsert,
     ``compact`` — activity-proportional delivery: when at most
                   ``spike_cap`` lanes spiked, compact the mask and gather
                   only those lanes' out-edges (``out_edge_table`` rows via
-                  the ``compact_gather`` kernel), then insert the fixed
+                  ``ops.compact_gather``), then insert the fixed
                   [spike_cap * k_out] batch through the queue's flat
                   batch insert.  More spikes than ``spike_cap`` fall back
                   to the dense branch under ``lax.cond`` — identical

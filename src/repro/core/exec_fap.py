@@ -162,8 +162,8 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
                   full scatter-min because min is exact in fp.
     fanout:       "dense" fans every spike over all E edges; "compact"
                   gathers only the <= spike_cap spiking lanes' out-edges
-                  (static ``out_edge_table`` rows via the
-                  ``compact_gather`` kernel) and inserts that fixed
+                  (static ``out_edge_table`` rows via
+                  ``ops.compact_gather``) and inserts that fixed
                   [spike_cap * k_out] batch — bursty regimes stop paying
                   O(E) per spiking round.  More spikes than spike_cap
                   fall back to the dense branch (identical events,
@@ -453,6 +453,7 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
                         solver=xc.solver_stats(sts), health=health)
         return res, sc.counters["rounds"]
 
+    run.jitted = _run         # the nullary fast path (AOT lower/compile)
     run.init_carry = init_carry
     run.round_body = round_body
     run.tenant_round = tenant_round   # (carry, iinj, active, k_qos) — serve

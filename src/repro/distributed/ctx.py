@@ -37,16 +37,6 @@ def _specs(dp, tp):
     }
 
 
-def _mesh_ctx(mesh):
-    """Context mesh across jax versions: jax.set_mesh (new), else
-    jax.sharding.use_mesh, else the Mesh object itself (jax 0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
 @contextlib.contextmanager
 def sharding_ctx(mesh):
     """Enable activation constraints for a (pod,)data,model mesh.
@@ -58,7 +48,7 @@ def sharding_ctx(mesh):
     prev = getattr(_state, "specs", None)
     _state.specs = specs
     try:
-        with _mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             yield
     finally:
         _state.specs = prev

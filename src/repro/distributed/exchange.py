@@ -14,8 +14,8 @@ knob (``transport="allgather"|"sparse"`` on ``build_fap_round``):
 ``sparse`` (the activity-scaled transport)
     * spike parcels: each shard compacts its (spiked, t_spike) into a
       destination-routed parcel buffer [n_shards, parcel_cap] of
-      (global id, time) entries via the sort-free cumsum-rank compaction
-      kernel (``kernels.event_wheel.ops.spike_compact``), then exchanges
+      (global id, time) entries via the sort-free prefix-rank compaction
+      (``kernels.event_wheel.ops.spike_compact``), then exchanges
       rows with one tiled ``all_to_all``: per-device parcel bytes are
       ``n_shards * parcel_cap * (4 + 8)`` — a function of the static
       activity cap, independent of N.  Parcel-cap overflow is detected,
@@ -86,7 +86,6 @@ class ExchangeSpec(NamedTuple):
     """Static sparse-transport geometry (python constants, closed over by
     jit — the ``WheelSpec`` of the communication layer)."""
     parcel_cap: int = 64          # parcel slots per (source, dest) shard pair
-    compact_impl: str = "pallas"  # spike_compact dispatch: "pallas" | "jnp"
     classes: tuple = ()           # ragged bucket-class caps (ascending);
     #                               () -> (cap//8, cap//2, cap) deduped
 
@@ -220,8 +219,7 @@ def sparse_transport(mesh, n: int, net, spec: ExchangeSpec,
         # one synapse into shard d (deduped by the static dest map)
         mask = jnp.logical_and(dest_l, spiked[:, None]).T  # [S, n_local]
         vals = jnp.broadcast_to(t_sp[None, :], mask.shape)
-        idx, ts, cnt = ew_ops.spike_compact(mask, vals, cap,
-                                            impl=spec.compact_impl)
+        idx, ts, cnt = ew_ops.spike_compact(mask, vals, cap)
         offset = shard_index(mesh, flat) * n_local
         gid = jnp.where(idx < n_local, idx + offset, n)  # sentinel -> n
         if len(classes) == 1:

@@ -132,10 +132,6 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     transport's per-round class choice made visible — cross-checked
     against the per-class HLO attribution in tests).
     """
-    from functools import partial
-
-    from jax.experimental.shard_map import shard_map
-
     if transport != "allgather" and not optimized:
         raise ValueError("sparse transport realises the shard-local "
                          "(optimized=True) round; the global path has no "
@@ -348,14 +344,14 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
             sts_specs = jax.tree_util.tree_map(
                 lambda leaf: P(flat, *([None] * (leaf.ndim - 1))), sts)
             n2 = P(flat, None)
-            fn_l = shard_map(
+            fn_l = jax.shard_map(
                 _round_local, mesh=mesh,
                 in_specs=(sts_specs, n2, n2, n2, P(flat), P(flat), P(flat),
                           P(flat), P(flat)) + carry_specs + tp.in_specs
                 + tbl_specs,
                 out_specs=(sts_specs, n2, n2, n2, P(flat), P(flat), P(), P(),
                            P(), P()) + carry_specs,
-                check_rep=False)
+                check_vma=False)
             return fn_l(sts, eq_t, eq_a, eq_g, pre, delay, w_a, w_g, iinj,
                         *rest)
         t_clock = sts.t
@@ -527,9 +523,13 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
         sts = jax.vmap(lambda y, i: bdf.reinit(model, 0.0, y, i, opts))(
             Y, iinj_v)
         eq = qops.make(n)
+        # placed as the round returns them: an unplaced carry is another
+        # input type, and round 0 would compile the round a second time
+        sts, eqs = jax.device_put((sts, (eq.t, eq.w_ampa, eq.w_gaba)),
+                                  (in_sh[0], tuple(in_sh[1:4])))
         hcarry = seed_hcarry(jnp.zeros((n,), jnp.float64)) if n_carry else ()
         rec = ev.make_spike_record(n, spk_cap)
-        return xc.SimCarry(sts, (eq.t, eq.w_ampa, eq.w_gaba), rec, hcarry,
+        return xc.SimCarry(sts, eqs, rec, hcarry,
                            {"n_ev": z64, "n_rs": z64, "dropped": z64,
                             "parcel_bytes": z64,
                             "rounds": jnp.zeros((), jnp.int32)})

@@ -65,7 +65,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            bq: int = 128, bk: int = 128,
                            offset: int | None = None, kv_len: int | None = None,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: [B, H, Sq, D]; k, v: [B, Hkv, Skv, D] -> [B, H, Sq, D].
 
     offset/kv_len describe the *real* (pre-padding) causal geometry:

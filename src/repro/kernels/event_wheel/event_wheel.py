@@ -11,7 +11,14 @@ is a full-width VPU column reduction over a [K, BN] tile.  The edge gather
 (t_clock[pre] -> cand) happens outside the kernel as a single XLA gather —
 the by-post edge layout makes the in-kernel work purely dense.
 
-VMEM/block = (K + 3) * BN * 8B; K = 16, BN = 256 -> ~39 KiB.
+VMEM/block = (K + 3) * BN * 8B; K = 16, BN = 256 -> ~39 KiB.  The
+horizon kernel is opt-in (``horizon_impl="fused"``) and does not compile
+for the TPU while clocks are f64.
+
+The main path's two kernels work on int32 only and compile for v5e:
+``compact_ids_pallas`` (the active-set, fan-out and parcel compaction)
+and ``segment_rank_pallas`` (the wheel insert's slot ranking).  Values of
+other dtypes are gathered by the callers in XLA with the emitted ids.
 """
 from __future__ import annotations
 
@@ -19,9 +26,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 BN_DEFAULT = 256
+# caps above this are tiled over a second grid axis (compact_ids_pallas)
+BCAP_DEFAULT = 1024
 
 
 def _horizon_kernel(cand_ref, t_clock_ref, hor_ref, score_ref, *,
@@ -37,7 +47,7 @@ def _horizon_kernel(cand_ref, t_clock_ref, hor_ref, score_ref, *,
 
 def horizon_score_pallas(cand, t_clock, *, t_end: float, horizon_cap: float,
                          eps: float = 1e-12, block_n: int = BN_DEFAULT,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """cand: [K, N] by-post candidates; t_clock: [N] -> (horizon[N], score[N]).
 
     N must be a multiple of block_n (the ops wrapper pads).
@@ -59,220 +69,129 @@ def horizon_score_pallas(cand, t_clock, *, t_end: float, horizon_cap: float,
     return hor[0], score[0]
 
 
-def _compact_kernel(mask_ref, vals_ref, idx_ref, val_ref, cnt_ref, *, cap, m):
-    """One grid step compacts one mask row: cumsum rank -> one-hot place.
-
-    The rank of event j among its row's survivors is ``cumsum(mask)[j]-1``
-    (the event-wheel's free-slot search run in reverse: rank -> position
-    instead of position -> rank); placement is a [cap, M] one-hot reduction —
-    compares and sums only, so the whole compaction stays sort- and
-    scatter-free inside the kernel.
-    """
-    msk = mask_ref[...].astype(jnp.int32)          # [1, M]
-    vals = vals_ref[...]                           # [1, M]
-    csum = jnp.cumsum(msk, axis=-1)
-    pos = csum - msk                               # 0-based rank where mask=1
-    total = csum[0, -1]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (cap, m), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (cap, m), 1)
-    hit = jnp.logical_and(pos == slot, msk == 1)   # [cap, M]
-    filled = slot[:, 0] < jnp.minimum(total, cap)
-    idx = jnp.sum(jnp.where(hit, col, 0), axis=1)
-    val = jnp.sum(jnp.where(hit, vals, 0.0), axis=1)
-    idx_ref[...] = jnp.where(filled, idx, m)[None, :].astype(jnp.int32)
-    val_ref[...] = jnp.where(filled, val, 0.0)[None, :]
-    cnt_ref[...] = total[None, None].astype(jnp.int32)
+# Index maps return int32 zeros: under x64 a bare python 0 traces to i64,
+# which the TPU lowering refuses.
+_Z = np.int32(0)
 
 
-def _compact_ids_kernel(mask_ref, ids_ref, cnt_ref, *, cap, bn):
-    """Blocked 1-D generalisation of ``_compact_kernel`` emitting gather
-    indices: the grid walks the mask in BN-wide blocks carrying the running
-    set-lane count in ``cnt_ref``, so the one-hot placement tile stays
-    [cap, BN] regardless of N (the row-at-once [cap, M] tile of the parcel
-    packer would blow VMEM at frontier-mask lengths).  Ids are accumulated
-    +1-biased so empty slots read 0 until the wrapper rewrites them to the
-    sentinel."""
-    step = pl.program_id(0)
+def _prefix_columns(m_row, bn):
+    """Inclusive and exclusive prefix sums of a 0/1 row i32[1, BN], as
+    i32[BN, 1] columns.  Pallas TPU has no ``cumsum``: the prefix sum is a
+    triangular-ones matmul, exact in f32 for any count below 2^24 (0/1
+    operands, integer partial sums)."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    mf = m_row.astype(jnp.float32)
+    dn = (((1,), (1,)), ((), ()))              # contract the lanes of both
+    incl = jax.lax.dot_general((c <= r).astype(jnp.float32), mf, dn,
+                               preferred_element_type=jnp.float32)
+    excl = jax.lax.dot_general((c < r).astype(jnp.float32), mf, dn,
+                               preferred_element_type=jnp.float32)
+    return incl.astype(jnp.int32), excl.astype(jnp.int32)
 
-    @pl.when(step == 0)
+
+def _compact_ids_kernel(mask_ref, ids_ref, cnt_ref, *, cb, bn):
+    """Grid (cap block c, mask block i): place the set lanes of mask block i
+    whose global rank falls in slots [c*CB, (c+1)*CB).  The rank of a set
+    lane is the running set count of earlier blocks (``cnt_ref``, reset at
+    the start of every cap-block sweep) plus its in-block exclusive prefix
+    sum; placement is a [BN, CB] one-hot column reduction — compares and
+    sums only, no sort, gather or scatter inside the kernel.  Ids
+    accumulate +1-biased so empty slots read 0 until the wrapper rewrites
+    them to the sentinel."""
+    c, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i == 0)
     def _init():
-        ids_ref[...] = jnp.zeros((1, cap), jnp.int32)
+        ids_ref[...] = jnp.zeros((1, cb), jnp.int32)
         cnt_ref[...] = jnp.zeros((1, 1), jnp.int32)
 
-    base = cnt_ref[0, 0]
-    msk = mask_ref[...]                            # [1, BN] i32
-    csum = jnp.cumsum(msk, axis=-1).astype(jnp.int32)
-    pos = base + csum - msk                        # global rank where mask=1
-    slot = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 1)
-    hit = jnp.logical_and(pos == slot, msk == 1)   # [cap, BN]
-    gid1 = step * bn + col + 1                     # global index, +1-biased
-    upd = jnp.sum(jnp.where(hit, gid1, 0), axis=1).astype(jnp.int32)
-    ids_ref[...] += upd[None, :]
-    cnt_ref[...] = (base + csum[0, -1]).astype(jnp.int32)[None, None]
+    base = cnt_ref[...]                                   # [1, 1]
+    incl, excl = _prefix_columns(mask_ref[...], bn)       # [BN, 1]
+    lane_set = (incl - excl) == 1
+    slot = c * cb + jax.lax.broadcasted_iota(jnp.int32, (bn, cb), 1)
+    hit = jnp.logical_and(base + excl == slot, lane_set)  # [BN, CB]
+    gid1 = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0) + 1
+    ids_ref[...] += jnp.sum(hit.astype(jnp.int32) * gid1, axis=0,
+                            keepdims=True, dtype=jnp.int32)
+    cnt_ref[...] = base + incl[bn - 1:bn, :]
 
 
 def compact_ids_pallas(mask, *, cap: int, block_n: int = BN_DEFAULT,
-                       interpret: bool = True):
+                       block_cap: int = BCAP_DEFAULT,
+                       interpret: bool = False):
     """Compact a bool[N] mask into the gather-id list of its set lanes.
 
     Returns (ids i32[cap] — indices of the first ``cap`` set lanes in
     index order, sentinel N for empty slots; count i32 — total set lanes,
     may exceed cap).  N must be a multiple of block_n (the ops wrapper
-    pads with zeros).
+    pads with zeros).  Caps above ``block_cap`` are tiled over a second
+    grid axis, so the [block_n, block_cap] placement tile bounds VMEM.
     """
     (N,) = mask.shape
     assert N % block_n == 0, (N, block_n)
-    kernel = functools.partial(_compact_ids_kernel, cap=cap, bn=block_n)
+    cb = cap if cap <= block_cap else block_cap
+    n_cb = -(-cap // cb)
+    kernel = functools.partial(_compact_ids_kernel, cb=cb, bn=block_n)
     acc, cnt = pl.pallas_call(
         kernel,
-        grid=(N // block_n,),
-        in_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i))],
-        out_specs=(pl.BlockSpec((1, cap), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))),
-        out_shape=(jax.ShapeDtypeStruct((1, cap), jnp.int32),
+        grid=(n_cb, N // block_n),
+        in_specs=[pl.BlockSpec((1, block_n), lambda c, i: (_Z, i))],
+        out_specs=(pl.BlockSpec((1, cb), lambda c, i: (_Z, c)),
+                   pl.BlockSpec((1, 1), lambda c, i: (_Z, _Z))),
+        out_shape=(jax.ShapeDtypeStruct((1, n_cb * cb), jnp.int32),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)),
         interpret=interpret,
     )(mask.astype(jnp.int32).reshape(1, N))
-    ids = jnp.where(acc[0] > 0, acc[0] - 1, N).astype(jnp.int32)
+    acc = acc[0, :cap]
+    ids = jnp.where(acc > 0, acc - 1, N).astype(jnp.int32)
     return ids, cnt[0, 0]
 
 
-def _compact_gather_kernel(mask_ref, tbl_ref, ids_ref, rows_ref, cnt_ref, *,
-                           cap, bn, mo):
-    """``_compact_ids_kernel`` generalised to also gather the rows of a
-    static [N, MO] table for the set lanes — the compact fan-out's
-    edge-index emitter: slot r of the output holds the table row of the
-    r-th set lane.  Same blocked [cap, BN] one-hot placement, accumulated
-    +1-biased across grid steps; the row gather is MO masked column
-    reductions (compares and sums only — no gather, scatter or sort
-    inside the kernel)."""
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        ids_ref[...] = jnp.zeros((1, cap), jnp.int32)
-        rows_ref[...] = jnp.zeros((cap, mo), jnp.int32)
-        cnt_ref[...] = jnp.zeros((1, 1), jnp.int32)
-
-    base = cnt_ref[0, 0]
-    msk = mask_ref[...]                            # [1, BN] i32
-    tbl = tbl_ref[...]                             # [BN, MO] i32
-    csum = jnp.cumsum(msk, axis=-1).astype(jnp.int32)
-    pos = base + csum - msk                        # global rank where mask=1
-    slot = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 1)
-    hit = jnp.logical_and(pos == slot, msk == 1)   # [cap, BN]
-    gid1 = step * bn + col + 1                     # global index, +1-biased
-    ids_ref[...] += jnp.sum(jnp.where(hit, gid1, 0),
-                            axis=1).astype(jnp.int32)[None, :]
-    for m in range(mo):
-        row1 = tbl[:, m][None, :] + 1              # [1, BN], +1-biased
-        rows_ref[:, m] += jnp.sum(jnp.where(hit, row1, 0),
-                                  axis=1).astype(jnp.int32)
-    cnt_ref[...] = (base + csum[0, -1]).astype(jnp.int32)[None, None]
-
-
-def compact_gather_pallas(mask, table, *, cap: int, fill: int,
-                          block_n: int = BN_DEFAULT, interpret: bool = True):
-    """Compact a bool[N] mask AND gather ``table``'s rows of its set lanes.
-
-    table: i32[N, MO].  Returns (ids i32[cap] — indices of the first
-    ``cap`` set lanes in index order, sentinel N for empty slots;
-    rows i32[cap, MO] — table[ids], ``fill`` for empty slots; count i32 —
-    total set lanes, may exceed cap).  N must be a multiple of block_n
-    (the ops wrapper pads).
-    """
-    (N,) = mask.shape
-    MO = table.shape[1]
-    assert N % block_n == 0, (N, block_n)
-    kernel = functools.partial(_compact_gather_kernel, cap=cap, bn=block_n,
-                               mo=MO)
-    acc, rows, cnt = pl.pallas_call(
-        kernel,
-        grid=(N // block_n,),
-        in_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i)),
-                  pl.BlockSpec((block_n, MO), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((1, cap), lambda i: (0, 0)),
-                   pl.BlockSpec((cap, MO), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))),
-        out_shape=(jax.ShapeDtypeStruct((1, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((cap, MO), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
-        interpret=interpret,
-    )(mask.astype(jnp.int32).reshape(1, N), table.astype(jnp.int32))
-    ids = jnp.where(acc[0] > 0, acc[0] - 1, N).astype(jnp.int32)
-    filled = acc[0] > 0
-    rows = jnp.where(filled[:, None], rows - 1, fill).astype(jnp.int32)
-    return ids, rows, cnt[0, 0]
-
-
-def _segment_rank_kernel(key_ref, rank_ref, *, be):
-    """Rank of each event within its key group, in event-index order: one
-    [BE, BE] pairwise-equality pass per (j-block, i-block) grid cell — no
-    per-round key table, no scatter, no sort.  Ranks accumulate across
-    i-blocks (grid dim 1 iterates the strictly-earlier blocks first) and
-    are clipped at ``max_rank`` by the wrapper."""
+def _segment_rank_kernel(kcol_ref, krow_ref, rank_ref, *, be):
+    """Grid (j-block, i-block): add to rank[j] the events of block i that
+    share j's key and come earlier.  Block i's keys arrive as a column and
+    block j's as a row, so the [BE_i, BE_j] equality tile reduces over
+    sublanes straight into a lane-dense rank row — no per-round key table,
+    no scatter, no sort.  Strictly-earlier blocks count whole; the
+    diagonal block counts i < j only; later blocks are skipped."""
     jb, ib = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ib == 0)
     def _init():
         rank_ref[...] = jnp.zeros((1, be), jnp.int32)
 
-    @pl.when(ib <= jb)
-    def _accum():
-        kj = key_ref[0, pl.dslice(jb * be, be)]        # [BE] this block's keys
-        ki = key_ref[0, pl.dslice(ib * be, be)]        # [BE] earlier block
-        same = kj[:, None] == ki[None, :]              # [BE, BE]
-        jj = jax.lax.broadcasted_iota(jnp.int32, (be, be), 0)
-        ii = jax.lax.broadcasted_iota(jnp.int32, (be, be), 1)
-        earlier = jnp.where(jb == ib, ii < jj, True)   # strict on diagonal
-        rank_ref[...] += jnp.sum(jnp.logical_and(same, earlier),
-                                 axis=1).astype(jnp.int32)[None, :]
+    def _accum(strict):
+        same = kcol_ref[...] == krow_ref[...]               # [BE_i, BE_j]
+        if strict:
+            ii = jax.lax.broadcasted_iota(jnp.int32, (be, be), 0)
+            jj = jax.lax.broadcasted_iota(jnp.int32, (be, be), 1)
+            same = jnp.logical_and(same, ii < jj)
+        rank_ref[...] += jnp.sum(same.astype(jnp.int32), axis=0,
+                                 keepdims=True, dtype=jnp.int32)
+
+    pl.when(ib < jb)(lambda: _accum(False))
+    pl.when(ib == jb)(lambda: _accum(True))
 
 
 def segment_rank_pallas(key, *, max_rank: int, block_e: int = 512,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Pairwise segment ranking for the wheel's generic insert: rank[j] =
     |{i < j : key[i] == key[j]}| clipped at ``max_rank`` — one VMEM pass
     over [BE, BE] tiles instead of ``segment_rank``'s ``max_rank`` rounds
-    of scatter-min over an O(n_keys) table.  E is padded to block_e by the
-    ops wrapper."""
+    of scatter-min over an O(n_keys) table.  Keys are int32; E is padded
+    to block_e by the ops wrapper."""
     (E,) = key.shape
     assert E % block_e == 0, (E, block_e)
     nb = E // block_e
-    kernel = functools.partial(_segment_rank_kernel, be=block_e)
+    k = key.astype(jnp.int32)
     rank = pl.pallas_call(
-        kernel,
+        functools.partial(_segment_rank_kernel, be=block_e),
         grid=(nb, nb),
-        in_specs=[pl.BlockSpec((1, E), lambda j, i: (0, 0))],
-        out_specs=pl.BlockSpec((1, block_e), lambda j, i: (0, j)),
+        in_specs=[pl.BlockSpec((block_e, 1), lambda j, i: (i, _Z)),
+                  pl.BlockSpec((1, block_e), lambda j, i: (_Z, j))],
+        out_specs=pl.BlockSpec((1, block_e), lambda j, i: (_Z, j)),
         out_shape=jax.ShapeDtypeStruct((1, E), jnp.int32),
         interpret=interpret,
-    )(key.astype(jnp.int32).reshape(1, E))
+    )(k.reshape(E, 1), k.reshape(1, E))
     return jnp.minimum(rank[0], max_rank)
-
-
-def compact_rows_pallas(mask, values, *, cap: int, interpret: bool = True):
-    """Row-wise sort-free stream compaction (the spike-parcel packer).
-
-    mask: [D, M] (bool/int — nonzero = keep); values: [D, M] f64.
-    Returns (idx i32[D, cap] — column index of the r-th kept element, sentinel
-    M for empty slots; vals f64[D, cap]; count i32[D] — total kept per row,
-    which may exceed cap: the overflow is the caller's drop counter).
-    """
-    D, M = mask.shape
-    row_in = pl.BlockSpec((1, M), lambda d: (d, 0))
-    row_out = pl.BlockSpec((1, cap), lambda d: (d, 0))
-    kernel = functools.partial(_compact_kernel, cap=cap, m=M)
-    idx, vals, cnt = pl.pallas_call(
-        kernel,
-        grid=(D,),
-        in_specs=[row_in, row_in],
-        out_specs=(row_out, row_out, pl.BlockSpec((1, 1), lambda d: (d, 0))),
-        out_shape=(jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((D, cap), values.dtype),
-                   jax.ShapeDtypeStruct((D, 1), jnp.int32)),
-        interpret=interpret,
-    )(mask.astype(jnp.int32), values)
-    return idx, vals, cnt[:, 0]

@@ -14,9 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import use_interpret
 from repro.kernels.event_wheel.event_wheel import (BN_DEFAULT,
-                                                   compact_gather_pallas,
                                                    compact_ids_pallas,
-                                                   compact_rows_pallas,
                                                    horizon_score_pallas,
                                                    segment_rank_pallas)
 
@@ -79,24 +77,42 @@ def fused_horizon_select(t_clock, pre_byk, delay_byk, *, t_end: float,
     return hor, runnable
 
 
-def spike_compact(mask, values, cap: int, *, impl: str = "pallas"):
+def auto_impls() -> dict:
+    """What ``impl="auto"`` resolves to for each dispatched op on the
+    current backend: the Pallas kernel on a TPU, the jnp/scatter path
+    elsewhere (interpret-mode grids walk the blocks in python)."""
+    kernel = not use_interpret()
+    return {"compact_ids": "pallas" if kernel else "jnp",
+            "compact_gather": "pallas" if kernel else "jnp",
+            "spike_compact": "pallas" if kernel else "jnp",
+            "segment_rank": "pallas" if kernel else "scatter"}
+
+
+def spike_compact(mask, values, cap: int, *, impl: str = "auto"):
     """Sort-free row-wise compaction of sparse spike streams into capped
     parcel buffers — the packer of the sparse spike-parcel transport
     (``repro.distributed.exchange``).
 
     mask: [D, M] (row d = the spikes destined for shard d); values: [D, M].
     Returns (idx i32[D, cap] — source column of each packed entry, sentinel M
-    marks empty slots; vals f64[D, cap]; count i32[D] — kept per row, may
-    exceed cap so callers can account drops).  ``impl="pallas"`` runs the
-    cumsum-rank kernel (interpret off-TPU); ``"jnp"`` the scatter oracle.
+    marks empty slots; vals [D, cap] — values at idx, 0 in empty slots;
+    count i32[D] — kept per row, may exceed cap so callers can account
+    drops).  ``impl="pallas"`` runs ``compact_ids`` per row (the kernel
+    emits int32 ids only; the values are gathered here in XLA),
+    ``"jnp"`` the scatter oracle, ``"auto"`` the kernel on a TPU only.
     """
-    if impl == "pallas":
-        return compact_rows_pallas(mask, values, cap=cap,
-                                   interpret=use_interpret())
+    if impl == "auto":
+        impl = auto_impls()["spike_compact"]
     if impl == "jnp":
         from repro.kernels.event_wheel import ref
         return ref.compact_rows_ref(mask, values, cap=cap)
-    raise ValueError(f"unknown spike_compact impl {impl!r}")
+    if impl != "pallas":
+        raise ValueError(f"unknown spike_compact impl {impl!r}")
+    M = mask.shape[1]
+    idx, cnt = jax.vmap(lambda m: compact_ids(m, cap, impl="pallas"))(mask)
+    vals = jnp.take_along_axis(values, jnp.minimum(idx, M - 1), axis=1)
+    vals = jnp.where(idx < M, vals, jnp.zeros((), values.dtype))
+    return idx, vals, cnt
 
 
 def compact_ids(mask, cap: int, *, impl: str = "auto",
@@ -107,14 +123,13 @@ def compact_ids(mask, cap: int, *, impl: str = "auto",
     Returns (ids i32[cap] — indices of the first ``cap`` set lanes in
     index order, sentinel N for empty slots; count i32 — total set lanes,
     which may exceed cap: the overflow rolls to a later dispatch, never
-    drops).  The same cumsum-rank machinery as ``spike_compact``,
-    generalised to emit the indices themselves: ``impl="pallas"`` runs the
-    blocked [cap, BN] one-hot kernel, ``"jnp"`` the O(N) scatter oracle;
-    ``"auto"`` picks pallas on real TPU and the scatter oracle elsewhere
-    (interpret-mode grids walk the blocks in python).
+    drops).  ``impl="pallas"`` runs the blocked [BN, cap] one-hot kernel
+    (prefix sums as triangular-ones matmuls), ``"jnp"`` the O(N) cumsum +
+    scatter oracle; ``"auto"`` picks the kernel on a TPU and the oracle
+    elsewhere.
     """
     if impl == "auto":
-        impl = "jnp" if use_interpret() else "pallas"
+        impl = auto_impls()["compact_ids"]
     if impl == "jnp":
         from repro.kernels.event_wheel import ref
         return ref.compact_ids_ref(mask, cap)
@@ -132,40 +147,25 @@ def compact_ids(mask, cap: int, *, impl: str = "auto",
 
 def compact_gather(mask, table, cap: int, *, fill: int = None,
                    impl: str = "auto", block_n: int = BN_DEFAULT):
-    """``compact_ids`` generalised to emit *gather rows*: compact a bool[N]
-    mask into the first ``cap`` set lanes AND gather the rows of a static
-    i32[N, MO] table for them in the same pass — the edge-index emitter of
-    the compact fan-out path (``fanout="compact"``), where ``table`` is
+    """``compact_ids`` plus the rows of a static i32[N, MO] table for the
+    compacted lanes — the edge-index emitter of the compact fan-out path
+    (``fanout="compact"``), where ``table`` is
     ``exec_common.out_edge_table`` and the emitted rows are the spiking
-    lanes' out-edge ids.
+    lanes' out-edge ids.  The rows are one XLA gather by the compacted
+    ids; ``impl`` selects the compaction (see ``compact_ids``).
 
     Returns (ids i32[cap] — set-lane indices in index order, sentinel N;
     rows i32[cap, MO] — table[ids], ``fill`` (default N, callers pass E)
     for empty slots; count i32 — total set lanes, may exceed cap: the
-    caller must fall back, never drop).  ``impl="auto"`` picks the blocked
-    Pallas kernel on real TPU and the scatter-oracle + XLA-gather path
-    elsewhere.
+    caller must fall back, never drop).
     """
     (n,) = mask.shape
     if fill is None:
         fill = n
-    if impl == "auto":
-        impl = "jnp" if use_interpret() else "pallas"
-    if impl == "jnp":
-        from repro.kernels.event_wheel import ref
-        return ref.compact_gather_ref(mask, table, cap, fill)
-    if impl != "pallas":
-        raise ValueError(f"unknown compact_gather impl {impl!r}")
-    n_pad = (-n) % block_n
-    m, tbl = mask, table
-    if n_pad:
-        m = jnp.concatenate([m, jnp.zeros((n_pad,), m.dtype)])
-        tbl = jnp.concatenate(
-            [tbl, jnp.zeros((n_pad, tbl.shape[1]), tbl.dtype)])
-    ids, rows, cnt = compact_gather_pallas(m, tbl, cap=cap, fill=fill,
-                                           block_n=block_n,
-                                           interpret=use_interpret())
-    return jnp.minimum(ids, n).astype(jnp.int32), rows, cnt
+    ids, cnt = compact_ids(mask, cap, impl=impl, block_n=block_n)
+    rows = jnp.where((ids < n)[:, None], table[jnp.minimum(ids, n - 1)],
+                     fill).astype(jnp.int32)
+    return ids, rows, cnt
 
 
 def segment_rank(key, n_keys: int, max_rank: int, *, impl: str = "auto",
@@ -194,7 +194,7 @@ def segment_rank(key, n_keys: int, max_rank: int, *, impl: str = "auto",
     path is already N-free and ignores the domain.
     """
     if impl == "auto":
-        impl = "scatter" if use_interpret() else "pallas"
+        impl = auto_impls()["segment_rank"]
     if domain not in ("global", "batch"):
         raise ValueError(f"unknown segment_rank domain {domain!r}")
     if impl == "scatter":

@@ -41,9 +41,9 @@ def compact_ids_ref(mask, cap: int):
 
 
 def compact_gather_ref(mask, table, cap: int, fill: int):
-    """Pure-jnp oracle for the compact-and-gather kernel: gather-id
-    compaction (``compact_ids_ref``) followed by one XLA row gather of the
-    static table.  Returns (ids i32[cap], rows i32[cap, MO] — table[ids]
+    """Pure-jnp oracle for ``ops.compact_gather``: gather-id compaction
+    (``compact_ids_ref``) followed by one XLA row gather of the static
+    table.  Returns (ids i32[cap], rows i32[cap, MO] — table[ids]
     with ``fill`` in empty slots, count i32)."""
     n = mask.shape[0]
     ids, cnt = compact_ids_ref(mask, cap)

@@ -44,7 +44,7 @@ def _hh_rhs_kernel(area_ref, v_ref, m_ref, h_ref, n_ref,
 
 
 def hh_rhs_pallas(area, v, m, h, n, *, block_n: int = BN_DEFAULT,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """area: [C]; v,m,h,n: [N, C] -> (dm, dh, dn, i_ion, g_tot) each [N, C]."""
     N, C = v.shape
     assert N % block_n == 0, (N, block_n)

@@ -29,10 +29,10 @@ def _hines_kernel(parent_ref, gax_ref, d_ref, b_ref, x_ref, *, n_comp):
     idx_t = jnp.arange(1).dtype      # platform default int (int32 on TPU)
 
     def load_row(ref, i):
-        return pl.load(ref, (pl.dslice(i, 1), slice(None)))      # [1, BN]
+        return ref[pl.ds(i, 1), :]                                # [1, BN]
 
     def store_row(ref, i, val):
-        pl.store(ref, (pl.dslice(i, 1), slice(None)), val)
+        ref[pl.ds(i, 1), :] = val
 
     # copy inputs into the output buffers we mutate in place
     x_ref[...] = b_ref[...]
@@ -71,7 +71,7 @@ def _hines_kernel(parent_ref, gax_ref, d_ref, b_ref, x_ref, *, n_comp):
 
 
 def hines_solve_pallas(parent, g_axial, d, b, *, block_n: int = BN_DEFAULT,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Solve the batched tree system.  d, b: [C, N] -> x: [C, N].
 
     parent: int32[C] shared topology; g_axial: [C] (same dtype as d).
@@ -109,10 +109,10 @@ def _hines_factor_kernel(parent_ref, gax_ref, d_ref, de_ref, *, n_comp):
         i = (C - 1 - idx).astype(idx_t)                           # C-1 .. 1
         p = parent_ref[i].astype(idx_t)
         a_i = gax_ref[i]
-        d_i = pl.load(de_ref, (pl.dslice(i, 1), slice(None)))
-        d_p = pl.load(de_ref, (pl.dslice(p, 1), slice(None)))
+        d_i = de_ref[pl.ds(i, 1), :]
+        d_p = de_ref[pl.ds(p, 1), :]
         f = a_i / d_i
-        pl.store(de_ref, (pl.dslice(p, 1), slice(None)), d_p - f * a_i)
+        de_ref[pl.ds(p, 1), :] = d_p - f * a_i
         return 0
 
     jax.lax.fori_loop(0, C - 1, elim, 0)
@@ -128,10 +128,10 @@ def _hines_solve_factored_kernel(parent_ref, gax_ref, de_ref, b_ref, x_ref,
     idx_t = jnp.arange(1).dtype
 
     def load_row(ref, i):
-        return pl.load(ref, (pl.dslice(i, 1), slice(None)))      # [1, BN]
+        return ref[pl.ds(i, 1), :]                                # [1, BN]
 
     def store_row(ref, i, val):
-        pl.store(ref, (pl.dslice(i, 1), slice(None)), val)
+        ref[pl.ds(i, 1), :] = val
 
     x_ref[...] = b_ref[...]
 
@@ -161,7 +161,7 @@ def _hines_solve_factored_kernel(parent_ref, gax_ref, de_ref, b_ref, x_ref,
 
 
 def hines_factor_pallas(parent, g_axial, d, *, block_n: int = BN_DEFAULT,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Eliminate the batched assembled diagonal.  d: [C, N] -> d_elim: [C, N].
 
     parent: int32[C] shared topology; g_axial: [C] (same dtype as d).
@@ -187,7 +187,7 @@ def hines_factor_pallas(parent, g_axial, d, *, block_n: int = BN_DEFAULT,
 
 def hines_solve_factored_pallas(parent, g_axial, d_elim, b, *,
                                 block_n: int = BN_DEFAULT,
-                                interpret: bool = True):
+                                interpret: bool = False):
     """Solve against a stored eliminated diagonal.  d_elim, b: [C, N] ->
     x: [C, N].  N must be a multiple of block_n (wrappers pad)."""
     C, N = d_elim.shape

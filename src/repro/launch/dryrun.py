@@ -48,12 +48,8 @@ def sds(shape, dtype):
 
 
 def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: old jax returns a list
-    of per-computation dicts, new jax a single dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, or {} where the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def input_specs(cfg: ArchConfig, shape: ShapeConfig):

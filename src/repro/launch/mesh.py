@@ -12,27 +12,14 @@ from __future__ import annotations
 import math
 
 import jax
-
-try:                                   # jax >= 0.5: explicit Auto axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto implicitly
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: pass axis_types=(Auto, ...) when
-    the installed jax supports it, plain make_mesh otherwise."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:              # make_mesh without axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-def _mk(shape, axes, devices):
-    return make_mesh_compat(shape, axes, devices=devices)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis explicitly ``Auto`` (sharding
+    propagated by GSPMD, as the rounds and the dry-run expect)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -44,14 +31,14 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devices)} — run "
             "with XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return _mk(shape, axes, devices[:need])
+    return make_mesh(shape, axes, devices[:need])
 
 
 def make_local_mesh(n_model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
     n = len(jax.devices())
     n_model = min(n_model, n)
-    return _mk((n // n_model, n_model), ("data", "model"),
+    return make_mesh((n // n_model, n_model), ("data", "model"),
                jax.devices()[: (n // n_model) * n_model])
 
 
@@ -60,4 +47,4 @@ def elastic_mesh(n_devices: int, model_parallel: int = 16):
     Resize = remesh + checkpoint restore with resharding (DESIGN.md §6)."""
     devices = jax.devices()[:n_devices]
     mp = math.gcd(model_parallel, n_devices)
-    return _mk((n_devices // mp, mp), ("data", "model"), devices)
+    return make_mesh((n_devices // mp, mp), ("data", "model"), devices)

@@ -185,6 +185,8 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     return sim_main(args) if args.sim else lm_main(args)
 
 

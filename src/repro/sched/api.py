@@ -13,6 +13,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 
 from repro.core import events as ev
 from repro.sched.wheel import WheelQueue, WheelSpec
@@ -145,9 +146,9 @@ def jaxpr_primitives(fn, *args, **kwargs) -> set:
                     walk(sub)
 
     def _subjaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:

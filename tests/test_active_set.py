@@ -19,6 +19,7 @@ from repro.core import exec_bsp, exec_common as xc, exec_fap
 from repro.core import morphology, network
 from repro.core.cell import CellModel
 from repro.core.topology import TOPOLOGIES, TopologyConfig
+from repro.kernels.event_wheel import event_wheel as ew_kernel
 from repro.kernels.event_wheel import ops as ew_ops
 from repro.kernels.event_wheel import ref as ew_ref
 
@@ -44,10 +45,19 @@ def iinj():
     return 0.16 + 0.004 * rng.standard_normal(N)
 
 
+# Final-state tolerance: spike trains stay identical event for event, but
+# XLA fuses the compact and dense executables differently, and a last-bit
+# difference can move an adaptive step.  Both runs still meet the BDF
+# error control (atol = 1e-3 mV / gate units), so their states may differ
+# by a few atol; 10 * atol bounds it.
+Y_FINAL_ATOL = 1e-2
+
+
 def _exact_same(a, b):
     assert np.array_equal(np.asarray(a.rec.times), np.asarray(b.rec.times))
     assert np.array_equal(np.asarray(a.rec.count), np.asarray(b.rec.count))
-    assert np.array_equal(np.asarray(a.y_final), np.asarray(b.y_final))
+    np.testing.assert_allclose(np.asarray(a.y_final), np.asarray(b.y_final),
+                               rtol=0, atol=Y_FINAL_ATOL)
     assert int(a.n_events) == int(b.n_events)
     assert int(a.dropped) == int(b.dropped) == 0
     assert not bool(a.failed) and not bool(b.failed)
@@ -75,6 +85,27 @@ def test_compact_ids_pallas_matches_ref(n, cap):
         got = np.asarray(ia)
         assert np.array_equal(got[: len(want)], want)
         assert np.all(got[len(want):] == n)
+
+
+@pytest.mark.parametrize("n,cap,block_cap", [(300, 37, 8), (1024, 600, 64)])
+def test_compact_ids_pallas_tiled_cap_matches_ref(n, cap, block_cap):
+    """Caps above ``block_cap`` tile over the kernel's second grid axis,
+    with a last cap block that is only partly used: fill fractions leave
+    cap blocks full, partly filled and empty."""
+    block_n = 128
+    rng = np.random.default_rng(n + cap)
+    for frac in (0.05, 0.3, 1.0):
+        mask = jnp.asarray(rng.random(n) < frac)
+        padded = jnp.concatenate([mask, jnp.zeros((-n) % block_n, bool)])
+        ib, cb = ew_kernel.compact_ids_pallas(
+            padded, cap=cap, block_n=block_n, block_cap=block_cap,
+            interpret=True)
+        ia, ca = ew_ref.compact_ids_ref(mask, cap)
+        assert int(ca) == int(cb) == int(mask.sum())
+        got = np.asarray(ib)
+        # the kernel's sentinel is the padded width; the ref's is n
+        np.testing.assert_array_equal(np.where(got >= n, n, got),
+                                      np.asarray(ia))
 
 
 def test_select_active_keeps_frontier_when_under_cap():

@@ -21,9 +21,9 @@ from repro.configs import ARCHS, get_config
 from repro.configs.base import ShapeConfig
 from repro.launch import dryrun
 from repro.launch.hlo_analysis import collective_bytes
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 out = {}
 for arch in ["smollm-135m", "qwen3-moe-30b-a3b", "rwkv6-7b", "zamba2-7b",
              "whisper-base", "internvl2-1b"]:

@@ -1,5 +1,5 @@
 """Exchange-layer units that need no multi-device mesh: the sort-free
-spike-compaction kernel, the static shard-frontier builder, and the
+spike compaction, the static shard-frontier builder, and the
 per-channel HLO byte attribution."""
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +14,7 @@ from repro.launch.hlo_analysis import collective_channel_bytes
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("cap", [4, 16, 64])
 def test_compact_pallas_matches_ref(seed, cap):
-    """The Pallas cumsum-rank compaction == the jnp scatter oracle, for
+    """The Pallas prefix-rank compaction == the jnp scatter oracle, for
     under- and over-full rows."""
     rng = np.random.default_rng(seed)
     D, M = 6, 41
@@ -36,6 +36,23 @@ def test_compact_pallas_matches_ref(seed, cap):
         assert (got[len(kept):] == M).all()          # sentinel pads
         np.testing.assert_allclose(np.asarray(v1[d])[: len(kept)],
                                    np.asarray(vals[d])[kept])
+
+
+def test_compact_pallas_tiled_cap_matches_ref():
+    """A parcel cap above the kernel's cap block (``BCAP_DEFAULT``) tiles
+    over several cap blocks; one row overflows the cap, one does not."""
+    from repro.kernels.event_wheel.event_wheel import BCAP_DEFAULT
+    rng = np.random.default_rng(3)
+    D, M = 2, BCAP_DEFAULT + 276
+    cap = BCAP_DEFAULT + 26
+    mask = jnp.asarray(rng.random((D, M)) < np.array([[0.98], [0.5]]))
+    vals = jnp.asarray(rng.uniform(0.0, 10.0, (D, M)))
+    i1, v1, c1 = ew_ops.spike_compact(mask, vals, cap, impl="pallas")
+    i2, v2, c2 = ew_ops.spike_compact(mask, vals, cap, impl="jnp")
+    assert int(c1[0]) > cap > int(c1[1])
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
 
 
 def test_compact_jaxpr_sort_free():

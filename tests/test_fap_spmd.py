@@ -31,9 +31,9 @@ from repro.distributed.exchange import ExchangeSpec
 from repro.distributed.fap_spmd import (PaperNeuroSpec, build_fap_round,
                                         run_fap_spmd)
 from repro.launch.hlo_analysis import collective_channel_bytes
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 model = CellModel(morphology.soma_only())
 n = 32
 net = network.make_network(n, k_in=4, seed=3)
@@ -53,8 +53,7 @@ runs = {
     "sparse": dict(optimized=True, transport="sparse",
                    exchange=ExchangeSpec(parcel_cap=8)),
     "sparse_wheel": dict(optimized=True, transport="sparse", queue="wheel",
-                         exchange=ExchangeSpec(parcel_cap=8,
-                                               compact_impl="jnp")),
+                         exchange=ExchangeSpec(parcel_cap=8)),
     # active-set compaction (ISSUE 4): shard-local compact -> step ->
     # scatter composed with the sparse transport; full shard width
     # (batch_cap=0) must be event-for-event identical to the dense batch

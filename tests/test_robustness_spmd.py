@@ -34,9 +34,9 @@ from repro.core.cell import CellModel
 from repro.core.topology import TopologyConfig
 from repro.distributed.exchange import ExchangeSpec
 from repro.distributed.fap_spmd import run_fap_spmd
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 model = CellModel(morphology.soma_only())
 n = 16
 net_u = network.make_network(n, k_in=4, seed=3)
@@ -86,7 +86,7 @@ def kill_resume(tag, netx, mesh2=None, **kw):
 sp = dict(optimized=True, transport="sparse",
           exchange=ExchangeSpec(parcel_cap=8))
 spw = dict(optimized=True, transport="sparse", queue="wheel",
-           exchange=ExchangeSpec(parcel_cap=8, compact_impl="jnp"))
+           exchange=ExchangeSpec(parcel_cap=8))
 base_ud = kill_resume("uniform/dense", net_u, **sp)
 kill_resume("uniform/wheel", net_u, **spw)
 kill_resume("block/dense", net_b, **sp)
@@ -94,7 +94,7 @@ kill_resume("block/wheel", net_b, **spw)
 
 # elastic resume: kill on the (2,2) mesh, resume on (4,1) — the
 # incremental-horizon carry is shard-relative and must be reseeded
-mesh41 = make_mesh_compat((4, 1), ("data", "model"))
+mesh41 = make_mesh((4, 1), ("data", "model"))
 kill_resume("elastic", net_u, mesh2=mesh41, batch="compact", batch_cap=8,
             horizon="incremental", **sp)
 
