@@ -378,10 +378,12 @@ def _step_impl(model, st: BDFState, t_limit, iinj, opts: BDFOptions,
                 st.jbad,
                 jnp.logical_or(st.nst - st.nstlp >= MSBP,
                                jnp.abs(gamrat - 1.0) > DGMAX))
-            factors = jax.lax.cond(
-                need,
-                lambda: model.newton_setup(ypred, gamma, mode=opts.precond),
-                lambda: st.factors)
+            with jax.named_scope("bdf.setup"):
+                factors = jax.lax.cond(
+                    need,
+                    lambda: model.newton_setup(ypred, gamma,
+                                               mode=opts.precond),
+                    lambda: st.factors)
             st = st._replace(
                 factors=factors,
                 gamma_saved=jnp.where(need, gamma, st.gamma_saved),
@@ -432,8 +434,9 @@ def _step_impl(model, st: BDFState, t_limit, iinj, opts: BDFOptions,
                 jnp.ones((), dtype), jnp.zeros((), jnp.int32),
                 jnp.zeros((), bool), jnp.zeros((), bool), st.nni, st.nfe,
                 f_first)
-        y, acor, _, crate, m_it, conv, _, nni, nfe, f_first = jax.lax.while_loop(
-            newton_cond, newton_body, init)
+        with jax.named_scope("bdf.newton"):
+            y, acor, _, crate, m_it, conv, _, nni, nfe, f_first = \
+                jax.lax.while_loop(newton_cond, newton_body, init)
         nsetups = st.nsetups if reuse else st.nsetups + (nni - st.nni)
         st = st._replace(nni=nni, nfe=nfe, nsetups=nsetups)
 

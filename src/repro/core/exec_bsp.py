@@ -255,20 +255,22 @@ def make_bsp_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
                                    (sts, eq, jnp.zeros((n,), bool),
                                     jnp.zeros((n,)), z, z,
                                     jnp.zeros((), jnp.int64), z, behind))
-            stats = xc.SchedStats(stats.runnable + n_behind,
-                                  stats.stepped + stepped,
-                                  stats.lanes + disp.astype(jnp.int64) * cap,
-                                  stats.rounds + disp)
+            stats = stats._replace(
+                runnable=stats.runnable + n_behind,
+                stepped=stats.stepped + stepped,
+                lanes=stats.lanes + disp.astype(jnp.int64) * cap,
+                rounds=stats.rounds + disp)
         else:
             active = jnp.ones((n,), bool)
             sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
                 sts, eq.t, eq.w_ampa, eq.w_gaba, horizon, active, iinj)
             eq = eq._replace(t=eq_t)
             nd, nrs = nd.sum(dtype=jnp.int32), nrs.sum(dtype=jnp.int32)
-            stats = xc.SchedStats(stats.runnable + n_behind,
-                                  stats.stepped + n_behind,
-                                  stats.lanes + n,
-                                  stats.rounds + 1)
+            stats = stats._replace(
+                runnable=stats.runnable + n_behind,
+                stepped=stats.stepped + n_behind,
+                lanes=stats.lanes + n,
+                rounds=stats.rounds + 1)
         rec = ev.record_spikes(rec, neuron_ids, t_sp, spiked)
         eq = spike_ins(eq, spiked, t_sp)
         return (sts, eq, rec, n_ev + nd, n_rs + nrs, stats), None

@@ -93,6 +93,24 @@ def runnable_mask(t_clock, horizon, eps: float = 1e-12):
 # ---------------------------------------------------------------------------
 # active-set compaction: step only the runnable frontier
 # ---------------------------------------------------------------------------
+# The names of the scheduler round's stages, each a ``jax.named_scope`` on
+# the work it does (``exec_fap._round``'s compact path, ``bdf._step_impl``).
+# They reach the compiled module's ``op_name`` metadata, where the
+# benchmark (``bench/stages.py``) reads device time per stage by
+# these names: a rename in the program is a rename here.
+ROUND_STAGES = (
+    "fap.horizon",          # dependency horizon + runnable mask (+ k caps)
+    "fap.compact",          # runnable mask -> [batch_cap] gather ids
+    "fap.gather",           # lanes and queue rows into the batch
+    "fap.advance",          # the vmapped variable-step BDF advance
+    "fap.scatter",          # lanes and queue rows back, spike record
+    "fap.fanout",           # spike fan-out + queue insert
+    "fap.horizon_update",   # incremental horizon rows of moved clocks
+    "bdf.setup",            # Newton setup (assemble + factor) gate
+    "bdf.newton",           # modified Newton: rhs + solve iterations
+)
+
+
 class SchedStats(NamedTuple):
     """Per-run active-set telemetry accumulated over scheduler rounds.
 
@@ -102,26 +120,41 @@ class SchedStats(NamedTuple):
     per compact dispatch).  ``stepped / lanes`` is the realized batch
     occupancy and ``1 - stepped / lanes`` the wasted-lane fraction — the
     dense-masking overhead the compact path removes.
+
+    ``horizon_live`` sums the live columns (ids below N) of the incremental
+    horizon, which recomputes ``batch_cap * (1 + max_out_degree)`` columns
+    a round, padding included; over ``rounds`` times that width it is the
+    stage's useful share (0 where the horizon is recomputed whole).
+    ``fanout_rounds`` counts the compact FAP rounds whose spike fan-out +
+    insert ran (those with a spike: the ``lax.cond`` guard); over
+    ``rounds`` it is the insert's duty share.  It stays 0 on the dense FAP
+    and BSP paths, which insert every round.  ``sched_metrics`` reports
+    both per round.
     """
     runnable: jnp.ndarray      # i64[] sum over rounds of runnable lanes
     stepped: jnp.ndarray       # i64[] sum of lanes that actually advanced
     lanes: jnp.ndarray         # i64[] sum of lanes dispatched to the stepper
     rounds: jnp.ndarray        # i32[] scheduler rounds (compact: dispatches)
+    horizon_live: jnp.ndarray  # i64[] live incremental horizon columns
+    fanout_rounds: jnp.ndarray  # i32[] compact rounds that ran the insert
 
     @staticmethod
     def zeros() -> "SchedStats":
-        z64 = jnp.zeros((), jnp.int64)
-        return SchedStats(z64, z64, z64, jnp.zeros((), jnp.int32))
+        z64, z32 = jnp.zeros((), jnp.int64), jnp.zeros((), jnp.int32)
+        return SchedStats(z64, z64, z64, z32, z64, z32)
 
 
 def sched_metrics(stats: SchedStats) -> dict:
     """Host-side summary of ``SchedStats`` (floats, safe for zero rounds)."""
     lanes = max(1, int(stats.lanes))
+    rounds = max(1, int(stats.rounds))
     return {
         "rounds": int(stats.rounds),
-        "runnable_per_round": float(stats.runnable) / max(1, int(stats.rounds)),
+        "runnable_per_round": float(stats.runnable) / rounds,
         "occupancy": float(stats.stepped) / lanes,
         "wasted_lane_frac": 1.0 - float(stats.stepped) / lanes,
+        "horizon_live_per_round": float(stats.horizon_live) / rounds,
+        "fanout_round_frac": float(stats.fanout_rounds) / rounds,
     }
 
 

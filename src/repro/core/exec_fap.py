@@ -255,83 +255,104 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         else:
             sts, eq, rec, n_ev, n_rs, stats, rounds = carry
         t_clock = sts.t
-        if incremental:
-            runnable = xc.runnable_mask(t_clock, horizon)
-        elif horizon_impl == "fused":
-            # fused kernel: min over in-edges + clamps + runnable (+ the
-            # earliest-K threshold when selection is sort-free too)
-            horizon, runnable = ew_ops.fused_horizon_select(
-                t_clock, pre_byk, delay_byk, t_end=t_end,
-                horizon_cap=horizon_cap, n_iters=n_bisect,
-                k_select=k_select if select == "threshold" else 0)
-        else:
-            horizon = xc.horizon_times(dnet, n, t_clock, t_end,
-                                       horizon_cap=horizon_cap)
-            runnable = xc.runnable_mask(t_clock, horizon)
-        if k_select > 0 and select == "threshold" and \
-                (incremental or horizon_impl == "scatter"):
-            score = jnp.where(runnable, t_clock, jnp.inf)
-            tau = ew_ops.select_threshold(score, k_select, n_iters=n_bisect)
-            runnable = jnp.logical_and(runnable, score <= tau)
-        if k_select > 0 and select == "sort":
-            # earliest-neuron-steps-next: keep only the K earliest runnable
-            score = jnp.where(runnable, t_clock, jnp.inf)
-            kth = jnp.sort(score)[min(k_select, n) - 1]
-            runnable = jnp.logical_and(runnable, score <= kth)
-        if active is not None:
-            # tenant-lane mask: an inactive lane advances nothing
-            runnable = jnp.logical_and(runnable, active)
-        if k_qos is not None:
-            # per-tenant QoS frontier cap: restrict to the k_qos earliest
-            # runnable neurons (traced k — one compiled round serves every
-            # class); k_qos <= 0 selects everything (tau = max finite)
-            score = jnp.where(runnable, t_clock, jnp.inf)
-            k_eff = jnp.where(k_qos > 0, jnp.minimum(k_qos, n), n)
-            tau = ew_ops.select_threshold(score, k_eff, n_iters=n_bisect)
-            runnable = jnp.logical_and(runnable, score <= tau)
-        n_runnable = runnable.sum(dtype=jnp.int64)
+        # each stage under its ``exec_common.ROUND_STAGES`` scope
+        with jax.named_scope("fap.horizon"):
+            if incremental:
+                runnable = xc.runnable_mask(t_clock, horizon)
+            elif horizon_impl == "fused":
+                # fused kernel: min over in-edges + clamps + runnable (+ the
+                # earliest-K threshold when selection is sort-free too)
+                horizon, runnable = ew_ops.fused_horizon_select(
+                    t_clock, pre_byk, delay_byk, t_end=t_end,
+                    horizon_cap=horizon_cap, n_iters=n_bisect,
+                    k_select=k_select if select == "threshold" else 0)
+            else:
+                horizon = xc.horizon_times(dnet, n, t_clock, t_end,
+                                           horizon_cap=horizon_cap)
+                runnable = xc.runnable_mask(t_clock, horizon)
+            if k_select > 0 and select == "threshold" and \
+                    (incremental or horizon_impl == "scatter"):
+                score = jnp.where(runnable, t_clock, jnp.inf)
+                tau = ew_ops.select_threshold(score, k_select,
+                                              n_iters=n_bisect)
+                runnable = jnp.logical_and(runnable, score <= tau)
+            if k_select > 0 and select == "sort":
+                # earliest-neuron-steps-next: keep only the K earliest
+                # runnable
+                score = jnp.where(runnable, t_clock, jnp.inf)
+                kth = jnp.sort(score)[min(k_select, n) - 1]
+                runnable = jnp.logical_and(runnable, score <= kth)
+            if active is not None:
+                # tenant-lane mask: an inactive lane advances nothing
+                runnable = jnp.logical_and(runnable, active)
+            if k_qos is not None:
+                # per-tenant QoS frontier cap: restrict to the k_qos
+                # earliest runnable neurons (traced k — one compiled round
+                # serves every class); k_qos <= 0 selects everything (tau =
+                # max finite)
+                score = jnp.where(runnable, t_clock, jnp.inf)
+                k_eff = jnp.where(k_qos > 0, jnp.minimum(k_qos, n), n)
+                tau = ew_ops.select_threshold(score, k_eff, n_iters=n_bisect)
+                runnable = jnp.logical_and(runnable, score <= tau)
+            n_runnable = runnable.sum(dtype=jnp.int64)
 
         if batch == "compact":
             # --- compact -> step -> scatter: only the runnable frontier
             # pays the step machinery ----------------------------------
-            ids, _ = xc.compact_frontier(runnable, t_clock, cap, n_bisect)
-            lane_ok = ids < n
-            idc = jnp.minimum(ids, n - 1)
-            sts_b = xc.gather_lanes(sts, idc)
-            t_b_prev = sts_b.t
-            eqt_b, eqa_b, eqg_b = sched.gather_rows(eq, idc)
-            sts_b, eqt_b, spiked_b, tsp_b, nd, nrs = vadvance(
-                sts_b, eqt_b, eqa_b, eqg_b, horizon[idc], lane_ok,
-                iinj_r[idc])
-            sts = xc.scatter_lanes(sts, sts_b, ids)
-            eq = sched.scatter_rows(eq, ids, eqt_b)
-            rec = ev.record_spikes(rec, ids, tsp_b, spiked_b)
-            # O(E) fan-out + insert only on rounds that actually spiked
-            # (identical either way: zero spikes insert nothing)
-            eq = jax.lax.cond(spiked_b.any(), _insert_spikes,
-                              lambda eq, *_: eq, eq, spiked_b, tsp_b, ids)
+            with jax.named_scope("fap.compact"):
+                ids, _ = xc.compact_frontier(runnable, t_clock, cap,
+                                             n_bisect)
+            with jax.named_scope("fap.gather"):
+                lane_ok = ids < n
+                idc = jnp.minimum(ids, n - 1)
+                sts_b = xc.gather_lanes(sts, idc)
+                t_b_prev = sts_b.t
+                eqt_b, eqa_b, eqg_b = sched.gather_rows(eq, idc)
+                hor_b, iinj_b = horizon[idc], iinj_r[idc]
+            with jax.named_scope("fap.advance"):
+                sts_b, eqt_b, spiked_b, tsp_b, nd, nrs = vadvance(
+                    sts_b, eqt_b, eqa_b, eqg_b, hor_b, lane_ok, iinj_b)
+            with jax.named_scope("fap.scatter"):
+                sts = xc.scatter_lanes(sts, sts_b, ids)
+                eq = sched.scatter_rows(eq, ids, eqt_b)
+                rec = ev.record_spikes(rec, ids, tsp_b, spiked_b)
+            with jax.named_scope("fap.fanout"):
+                # O(E) fan-out + insert only on rounds that actually
+                # spiked (identical either way: zero spikes insert nothing)
+                spiked_any = spiked_b.any()
+                eq = jax.lax.cond(spiked_any, _insert_spikes,
+                                  lambda eq, *_: eq, eq, spiked_b, tsp_b, ids)
+            live = 0
             if incremental:
-                # only rows fed by a moved clock can change: the batch's
-                # out-neighbours, plus the batch lanes' own cap terms
-                moved = jnp.logical_and(lane_ok, sts_b.t != t_b_prev)
-                outp = jnp.where(moved[:, None], out_post[idc], n)
-                p = jnp.concatenate([ids, outp.reshape(-1)])
-                horizon = horizon.at[p].set(_horizon_rows(sts.t, p),
-                                            mode="drop")
-            stats = xc.SchedStats(stats.runnable + n_runnable,
-                                  stats.stepped + lane_ok.sum(dtype=jnp.int64),
-                                  stats.lanes + cap,
-                                  stats.rounds + 1)
+                with jax.named_scope("fap.horizon_update"):
+                    # only rows fed by a moved clock can change: the
+                    # batch's out-neighbours, plus the batch lanes' own
+                    # cap terms
+                    moved = jnp.logical_and(lane_ok, sts_b.t != t_b_prev)
+                    outp = jnp.where(moved[:, None], out_post[idc], n)
+                    p = jnp.concatenate([ids, outp.reshape(-1)])
+                    horizon = horizon.at[p].set(_horizon_rows(sts.t, p),
+                                                mode="drop")
+                    live = (p < n).sum(dtype=jnp.int64)
+            stats = stats._replace(
+                runnable=stats.runnable + n_runnable,
+                stepped=stats.stepped + lane_ok.sum(dtype=jnp.int64),
+                lanes=stats.lanes + cap,
+                rounds=stats.rounds + 1,
+                horizon_live=stats.horizon_live + live,
+                fanout_rounds=stats.fanout_rounds
+                + spiked_any.astype(jnp.int32))
         else:
             sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
                 sts, eq.t, eq.w_ampa, eq.w_gaba, horizon, runnable, iinj_r)
             eq = eq._replace(t=eq_t)
             rec = ev.record_spikes(rec, neuron_ids, t_sp, spiked)
             eq = spike_ins(eq, spiked, t_sp)
-            stats = xc.SchedStats(stats.runnable + n_runnable,
-                                  stats.stepped + n_runnable,
-                                  stats.lanes + n,
-                                  stats.rounds + 1)
+            stats = stats._replace(
+                runnable=stats.runnable + n_runnable,
+                stepped=stats.stepped + n_runnable,
+                lanes=stats.lanes + n,
+                rounds=stats.rounds + 1)
         out = (sts, eq, rec, n_ev + nd.sum(dtype=jnp.int32),
                n_rs + nrs.sum(dtype=jnp.int32), stats, rounds + 1)
         if incremental:

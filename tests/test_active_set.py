@@ -286,8 +286,8 @@ def test_batch_cap_auto_picks_from_telemetry(model, iinj):
     r_a, _ = run()
     _exact_same(r_d, r_a)
     # the picker itself: mean frontier * slack, pow2, clipped
-    s = xc.SchedStats(jnp.asarray(1000, jnp.int64), jnp.asarray(0, jnp.int64),
-                      jnp.asarray(0, jnp.int64), jnp.asarray(10, jnp.int32))
+    s = xc.SchedStats.zeros()._replace(runnable=jnp.asarray(1000, jnp.int64),
+                                       rounds=jnp.asarray(10, jnp.int32))
     assert xc.auto_batch_cap(s, 1 << 16) == 256      # 2*100 -> 256
     assert xc.auto_batch_cap(s, 64) == 64            # clipped at n
     assert xc.auto_batch_cap(xc.SchedStats.zeros(), 1 << 16) == 32
