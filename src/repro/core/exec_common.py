@@ -105,7 +105,7 @@ ROUND_STAGES = (
     "fap.advance",          # the vmapped variable-step BDF advance
     "fap.scatter",          # lanes and queue rows back, spike record
     "fap.fanout",           # spike fan-out + queue insert
-    "fap.horizon_update",   # incremental horizon rows of moved clocks
+    "fap.horizon_update",   # moved clocks pushed to their in-edge slots
     "bdf.setup",            # Newton setup (assemble + factor) gate
     "bdf.newton",           # modified Newton: rhs + solve iterations
 )
@@ -121,9 +121,10 @@ class SchedStats(NamedTuple):
     occupancy and ``1 - stepped / lanes`` the wasted-lane fraction — the
     dense-masking overhead the compact path removes.
 
-    ``horizon_live`` sums the live columns (ids below N) of the incremental
-    horizon, which recomputes ``batch_cap * (1 + max_out_degree)`` columns
-    a round, padding included; over ``rounds`` times that width it is the
+    ``horizon_live`` sums what the incremental horizon touches: the batch's
+    lanes (their cap terms) and the real out-edge slots of moved lanes,
+    which the round's push writes, of the ``batch_cap * (1 +
+    max_out_degree)`` it could; over ``rounds`` times that width it is the
     stage's useful share (0 where the horizon is recomputed whole).
     ``fanout_rounds`` counts the compact FAP rounds whose spike fan-out +
     insert ran (those with a spike: the ``lax.cond`` guard); over
@@ -181,7 +182,7 @@ def out_tables(net):
     """Host-side by-pre grouping of the static edge list: per neuron i the
     postsynaptic targets (sentinel N) and edge-list positions (sentinel E)
     of its out-edges, padded to the max out-degree.  Builders that need
-    both tables (incremental horizon + compact fan-out) call this once —
+    both tables (SPMD incremental horizon + compact fan-out) call it once —
     the O(E log E) grouping is the expensive part, not the two views."""
     pre = np.asarray(net.pre)
     post = np.asarray(net.post)
@@ -199,17 +200,14 @@ def out_tables(net):
     return post_t, edge_t
 
 
-def out_post_table(net) -> np.ndarray:
-    """Host-side static out-neighbour table: row i lists the postsynaptic
-    targets of neuron i's out-edges, padded with the sentinel N.
-
-    The compact FAP round uses it for *incremental* horizon maintenance:
-    when only the [batch_cap] advanced lanes moved, the only horizon rows
-    that can change are their out-neighbours (plus the lanes' own
-    clock-cap terms) — O(cap * max_out_degree) per round instead of the
-    O(E) full scatter-min.
-    """
-    return out_tables(net)[0]
+def in_edge_slots(edge_t, n: int, k: int) -> np.ndarray:
+    """Host-side map of ``out_tables``' edge table onto the [K, N] by-post
+    layout of a grouped net (``ops.by_post_layout``): edge ``e = j * K +
+    k`` sits at flat slot ``k * N + j``; the padding E stays E (= K * N).
+    The incremental horizon pushes a moved clock to these slots."""
+    e = np.asarray(edge_t, np.int64)
+    E = n * k
+    return np.where(e < E, (e % k) * n + e // k, E).astype(np.int32)
 
 
 def out_edge_table(net) -> np.ndarray:
@@ -406,8 +404,9 @@ class SimCarry(NamedTuple):
     the SPMD round's raw ``(t, w_ampa, w_gaba)`` planes), same spike-record
     cursor (``rec``) and the same solver/sched/comm counters.
 
-    ``hcarry`` holds the incremental-horizon carry (horizon, previous
-    boundary clocks, moved ids) where a runner maintains one — the only
+    ``hcarry`` holds the incremental-horizon carry where a runner
+    maintains one (``exec_fap``: the in-edge clock table; the SPMD round:
+    horizon, previous boundary clocks, moved ids) — the only layout- and
     mesh-shape-*dependent* leaves.  On elastic resume onto a different
     mesh shape ``restore_sim_checkpoint`` skips exactly these and the
     caller reseeds them from the restored clocks (a full recompute, which

@@ -156,10 +156,16 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
                   runs under a ``lax.cond`` (a semantic no-op on
                   spike-free rounds, the common case off the burst
                   regimes), and with the scatter horizon on a grouped net
-                  the dependency horizon is maintained *incrementally* —
-                  only rows whose pre clocks moved (the batch's
-                  out-neighbours) are recomputed, bit-identical to the
-                  full scatter-min because min is exact in fp.
+                  the dependency horizon is maintained *incrementally*:
+                  the carry holds the in-edge clock table ``tin``, the
+                  [K, N] table ``t[pre_byk]`` stored row-major as f64[K *
+                  N] (flat, so the TPU's scatter needs no relayout of
+                  it), each round pushes the batch's moved clocks into
+                  their out-edge slots (one scatter of batch_cap *
+                  max_out_degree indices), and the horizon is one
+                  dense pass min_k(tin + delay) clamped at t_end and t +
+                  cap — bit-identical to the full scatter-min because min
+                  is exact in fp.  ``run.horizon_of(carry)`` derives it.
     fanout:       "dense" fans every spike over all E edges; "compact"
                   gathers only the <= spike_cap spiking lanes' out-edges
                   (static ``out_edge_table`` rows via
@@ -217,19 +223,36 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
     edge_tbl = None
     if incremental:
         pre_byk, delay_byk = ew_ops.by_post_layout(net)
-        post_np, edge_np = xc.out_tables(net)    # one grouping pass serves
-        out_post = jnp.asarray(post_np)          # the horizon ([N,MO], sent.
-        if fanout == "compact":                  # n) and the fan-out tables
+        # one grouping pass serves the push ([N, MO] slots of the in-edge
+        # clock table) and the compact fan-out's edge table
+        _, edge_np = xc.out_tables(net)
+        edge_slot = jnp.asarray(xc.in_edge_slots(edge_np, n,
+                                                 pre_byk.shape[0]))
+        n_slots = int(pre_byk.size)
+        delay_flat = delay_byk.reshape(-1)
+        if fanout == "compact":
             edge_tbl = edge_np
 
-    def _horizon_rows(t_clock, p):
-        """Recompute horizon for the (sentinel-padded) post set ``p`` from
-        current clocks — the same min/clamp chain as the full scatter-min
-        (min is exact, so incremental == full, bitwise)."""
-        pc = jnp.minimum(p, n - 1)
-        cand = t_clock[pre_byk[:, pc]] + delay_byk[:, pc]     # [K, |p|]
-        hor_p = jnp.minimum(jnp.min(cand, axis=0), t_end)
-        return jnp.minimum(hor_p, t_clock[pc] + horizon_cap)
+    def _horizon_of(tin, t_clock):
+        """The dependency horizon from the in-edge clock table: the same
+        adds and exact mins as the full scatter-min, so bitwise equal.
+        The min over K folds the table's K rows elementwise."""
+        cand = tin + delay_flat
+        hor = cand[:n]
+        for k in range(1, pre_byk.shape[0]):
+            hor = jnp.minimum(hor, cand[k * n:(k + 1) * n])
+        hor = jnp.minimum(hor, t_end)
+        return jnp.minimum(hor, t_clock + horizon_cap)
+
+    def _push_clocks(tin, idc, moved, t_b):
+        """Write each moved lane's clock into its out-edge slots of ``tin``
+        (each edge has one pre, so no two lanes write one slot); padding
+        and lanes that did not move point past the table and drop.
+        Returns the table and the number of slots written."""
+        slots = jnp.where(moved[:, None], edge_slot[idc], n_slots)
+        vals = jnp.broadcast_to(t_b[:, None], slots.shape)
+        return (tin.at[slots].set(vals, mode="drop"),
+                (slots < n_slots).sum(dtype=jnp.int64))
 
     spike_ins = xc.make_spike_insert(net, dnet, qops, qinsert, fanout, s_cap,
                                      edge_table=edge_tbl)
@@ -251,13 +274,14 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         per-tenant QoS cap; 0 = unlimited).  Both default to None, which
         traces nothing extra — the single-tenant runners are untouched."""
         if incremental:
-            sts, eq, rec, horizon, n_ev, n_rs, stats, rounds = carry
+            sts, eq, rec, tin, n_ev, n_rs, stats, rounds = carry
         else:
             sts, eq, rec, n_ev, n_rs, stats, rounds = carry
         t_clock = sts.t
         # each stage under its ``exec_common.ROUND_STAGES`` scope
         with jax.named_scope("fap.horizon"):
             if incremental:
+                horizon = _horizon_of(tin, t_clock)
                 runnable = xc.runnable_mask(t_clock, horizon)
             elif horizon_impl == "fused":
                 # fused kernel: min over in-edges + clamps + runnable (+ the
@@ -325,15 +349,11 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
             live = 0
             if incremental:
                 with jax.named_scope("fap.horizon_update"):
-                    # only rows fed by a moved clock can change: the
-                    # batch's out-neighbours, plus the batch lanes' own
-                    # cap terms
+                    # only the in-edge slots of moved clocks change; the
+                    # lanes' own cap terms follow from the clocks
                     moved = jnp.logical_and(lane_ok, sts_b.t != t_b_prev)
-                    outp = jnp.where(moved[:, None], out_post[idc], n)
-                    p = jnp.concatenate([ids, outp.reshape(-1)])
-                    horizon = horizon.at[p].set(_horizon_rows(sts.t, p),
-                                                mode="drop")
-                    live = (p < n).sum(dtype=jnp.int64)
+                    tin, pushed = _push_clocks(tin, idc, moved, sts_b.t)
+                    live = lane_ok.sum(dtype=jnp.int64) + pushed
             stats = stats._replace(
                 runnable=stats.runnable + n_runnable,
                 stepped=stats.stepped + lane_ok.sum(dtype=jnp.int64),
@@ -356,7 +376,7 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         out = (sts, eq, rec, n_ev + nd.sum(dtype=jnp.int32),
                n_rs + nrs.sum(dtype=jnp.int32), stats, rounds + 1)
         if incremental:
-            out = out[:3] + (horizon,) + out[3:]
+            out = out[:3] + (tin,) + out[3:]
         return out
 
     def round_body(carry):
@@ -388,10 +408,16 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         z = jnp.zeros((), jnp.int32)
         carry = sts, eq, rec, z, z, xc.SchedStats.zeros(), z
         if incremental:
-            hor0 = xc.horizon_times(dnet, n, sts.t, t_end,
-                                    horizon_cap=horizon_cap)
-            carry = carry[:3] + (hor0,) + carry[3:]
+            carry = carry[:3] + (_tin_of(sts.t),) + carry[3:]
         return carry
+
+    def _tin_of(t_clock):
+        """The in-edge clock table of ``t_clock``: one gather."""
+        return t_clock[pre_byk].reshape(-1)
+
+    def horizon_of(carry):
+        """The dependency horizon an incremental round's carry implies."""
+        return _horizon_of(carry[3], carry[0].t)
 
     @jax.jit
     def _run():
@@ -405,13 +431,14 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
                          solver=xc.solver_stats(sts)), rounds
 
     # --- preemption tolerance: SimCarry <-> round-carry packing ----------
-    # hcarry holds the incremental horizon (mesh/layout-dependent); all
-    # solver/sched counters ride a dict so repro.checkpoint snapshots the
-    # WHOLE round state leaf-for-leaf.
+    # hcarry holds the in-edge clock table (layout-dependent; a snapshot
+    # of another layout is reseeded from its clocks); all solver/sched
+    # counters ride a dict so repro.checkpoint snapshots the WHOLE round
+    # state leaf-for-leaf.
     def pack(c):
         if incremental:
-            sts, eq, rec, horizon, n_ev, n_rs, stats, rounds = c
-            h = (horizon,)
+            sts, eq, rec, tin, n_ev, n_rs, stats, rounds = c
+            h = (tin,)
         else:
             sts, eq, rec, n_ev, n_rs, stats, rounds = c
             h = ()
@@ -425,6 +452,12 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         return c[:3] + tuple(sc.hcarry) + c[3:] if incremental else c
 
     jround = None
+    # a snapshot of another horizon carry (the horizon itself, before the
+    # in-edge clock table) is reseeded from its clocks: by shape, and by
+    # this fingerprint where the shapes coincide (K = 1)
+    ckpt_layout = dict(
+        reseed=lambda sc: sc._replace(hcarry=(_tin_of(sc.sts.t),)),
+        fingerprint={"hcarry": "in_edge_clocks"}) if incremental else {}
 
     def run(checkpoint_every: int = 0, ckpt_dir=None, resume: bool = False,
             fault=None, watchdog=None, max_rollbacks: int = 2,
@@ -454,7 +487,7 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
             def health_of(sc, t_prev):
                 return xc.health_check(
                     sc.sts, t_prev,
-                    horizon=sc.hcarry[0] if incremental else None,
+                    horizon=horizon_of(unpack(sc)) if incremental else None,
                     horizon_cap=horizon_cap)
 
         sc, health = xc.run_checkpointed(
@@ -463,7 +496,8 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
             lambda sc: bool(cond(unpack(sc))),
             ckpt_dir=ckpt_dir, checkpoint_every=checkpoint_every,
             resume=resume, keep=ckpt_keep, fault=fault,
-            health_of=health_of, max_rollbacks=max_rollbacks)
+            health_of=health_of, max_rollbacks=max_rollbacks,
+            **ckpt_layout)
         sts, eq, rec = sc.sts, sc.eq, sc.rec
         health["dropped_events"] = int(eq.dropped)
         res = RunResult(rec, sts.nst.sum(), sc.counters["n_ev"],
@@ -481,6 +515,7 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
     run.cond = cond
     run.pack = pack           # carry tuple <-> SimCarry (checkpoint tests)
     run.unpack = unpack
+    run.horizon_of = horizon_of   # incremental carry -> its horizon
     run.batch_cap = cap
     run.spike_cap = s_cap
     return run

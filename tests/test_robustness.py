@@ -145,7 +145,7 @@ def test_simcarry_roundtrip_every_field(runners, tmp_path):
     # sanity: the snapshot really carries the PR 6 solver-state fields
     for f in ("gamma_saved", "nstlp", "factors", "zn", "t", "h"):
         assert hasattr(sc.sts, f)
-    assert len(sc.hcarry) == 1          # incremental horizon leg present
+    assert len(sc.hcarry) == 1          # in-edge clock table leg present
     xc.save_sim_checkpoint(str(tmp_path), 7, sc, extras={"k": 1})
     sc2, extras, skipped = xc.restore_sim_checkpoint(str(tmp_path), 7, sc)
     assert extras == {"k": 1} and skipped == []

@@ -53,9 +53,10 @@ def test_compact_lab_round_carries_every_stage(model):
 
 
 def test_round_counters_match_the_carry(model):
-    """Over host-stepped rounds, the incremental horizon's live columns
-    and the rounds that ran the spike insert equal what the carry before
-    and after each round gives on the host."""
+    """Over host-stepped rounds, what the incremental horizon touches (the
+    batch's lanes and the out-edge slots of moved clocks) and the rounds
+    that ran the spike insert equal what the carry before and after each
+    round gives on the host."""
     n, cap = 64, 16
     net = network.make_network(n, k_in=4, seed=3)
     iinj = np.linspace(0.15, 0.6, n)
@@ -68,7 +69,7 @@ def test_round_counters_match_the_carry(model):
     want = {"horizon_live": 0, "fanout_rounds": 0}
     batch_live = 0
     for _ in range(40):
-        sts, rec, horizon = carry[0], carry[2], carry[3]
+        sts, rec, horizon = carry[0], carry[2], run.horizon_of(carry)
         t0 = np.asarray(sts.t)
         runnable = int((t0 < np.asarray(horizon) - 1e-12).sum())
         count0 = int(np.asarray(rec.count).sum())
