@@ -94,7 +94,8 @@ def runnable_mask(t_clock, horizon, eps: float = 1e-12):
 # active-set compaction: step only the runnable frontier
 # ---------------------------------------------------------------------------
 # The names of the scheduler round's stages, each a ``jax.named_scope`` on
-# the work it does (``exec_fap._round``'s compact path, ``bdf._step_impl``).
+# the work it does (``exec_fap._round``'s compact path, the SPMD round
+# ``fap_spmd._round_local``, ``bdf._step_impl``).
 # They reach the compiled module's ``op_name`` metadata, where the
 # benchmark (``bench/stages.py``) reads device time per stage by
 # these names: a rename in the program is a rename here.
@@ -108,6 +109,14 @@ ROUND_STAGES = (
     "fap.horizon_update",   # moved clocks pushed to their in-edge slots
     "bdf.setup",            # Newton setup (assemble + factor) gate
     "bdf.newton",           # modified Newton: rhs + solve iterations
+)
+
+# The two channels of the SPMD round (``fap_spmd._round_local``), which
+# carries every stage above besides: the transport's clock notification and
+# its spike-parcel exchange.  A single-device round has neither.
+CHANNEL_STAGES = (
+    "fap.notify",           # clocks to the other shards (Transport.notify)
+    "fap.exchange",         # spikes as parcels (Transport.exchange)
 )
 
 

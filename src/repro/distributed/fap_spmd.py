@@ -33,14 +33,19 @@ Communication is owned entirely by the transport (the
 Each channel's collectives are tagged with ``jax.named_scope`` so
 ``launch.hlo_analysis.collective_channel_bytes`` attributes per-channel
 bytes in the compiled HLO — the bytes-scale-with-activity claim is
-asserted by tests/benchmarks, not assumed.
+asserted by tests/benchmarks, not assumed.  Each stage of the round runs
+under its ``exec_common.ROUND_STAGES`` / ``CHANNEL_STAGES`` scope
+(``fap.notify``, ``fap.horizon``, ..., ``fap.exchange``, ``fap.fanout``),
+where the benchmark reads device time per stage.
 
 Parcel-cap and queue overflow stay detected-never-silent: every round
-returns a ``dropped`` counter (queue + transport), accumulated into
-``RunResult.dropped`` by ``run_fap_spmd``.
+adds its ``dropped`` count (queue + transport) to the carry's counters,
+which ``run_fap_spmd`` reports as ``RunResult.dropped``.
 
 ``build_fap_round`` returns (fn, example_args, in_shardings) so the dry-run
-can lower it on the 16x16 and 2x16x16 meshes like any LM cell.
+can lower it on the 16x16 and 2x16x16 meshes like any LM cell;
+``make_fap_spmd_runner`` wraps the same round as a runner (init, round,
+device-side cond), which ``run_fap_spmd`` and the benchmark drive.
 """
 from __future__ import annotations
 
@@ -54,6 +59,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import sched
 from repro.core import bdf
+from repro.core import events as ev
 from repro.core import exec_common as xc
 from repro.core.cell import CellModel
 from repro.core.exec_bsp import make_vardt_advance
@@ -78,7 +84,8 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                     exchange: ExchangeSpec = ExchangeSpec(), net=None,
                     batch: str = "dense", batch_cap: int = 0,
                     fanout: str = "dense", spike_cap: int = 0,
-                    horizon: str = "full", move_cap: int = 0):
+                    horizon: str = "full", move_cap: int = 0,
+                    spk_cap: int = 128):
     """optimized=False: paper-faithful baseline — horizon scatter-min and
     event insert as *global* ops, lowered by GSPMD (collective-heavy: with
     queue="dense" the global argsort in the insert becomes a distributed
@@ -122,15 +129,26 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     full scatter-min because min is exact.  Requires optimized +
     batch="compact" + a sparse-family transport (the frontier tables).
     The round then carries (horizon, prev_boundary_clocks, moved_ids)
-    through its inputs/outputs; ``run_fap_spmd`` seeds them.
+    in ``SimCarry.hcarry``; ``make_fap_spmd_runner`` seeds them.
 
-    The round returns (sts, eq_t, eq_a, eq_g, spiked, t_spike, n_deliv,
-    n_resets, dropped, parcel_bytes[, horizon, prev_bnd, moved_ids]);
-    ``dropped`` counts this round's queue overflow plus sparse-transport
-    parcel overflow (detected, never silent); ``parcel_bytes`` is the
-    transport's realized parcel-channel payload this round (the ragged
-    transport's per-round class choice made visible — cross-checked
-    against the per-class HLO attribution in tests).
+    Returns ``(fap_round, (carry, static), (carry_sh, static_sh))``:
+    ``fap_round(carry, static) -> carry`` advances one round.  ``carry`` is
+    the whole round state as one ``exec_common.SimCarry``: the sharded
+    BDF states, queue planes ``(t, w_ampa, w_gaba)`` and spike record
+    (``spk_cap`` spikes a neuron, sharded by neuron and written inside
+    the round), the horizon carry, and the counters ``n_ev``, ``n_rs``,
+    ``dropped`` (this round's queue overflow plus sparse-transport parcel
+    overflow: detected, never silent), ``parcel_bytes`` (the parcel
+    payload each device shipped: the ragged transport's per-round class
+    choice made visible, cross-checked against the per-class HLO
+    attribution in tests), ``rounds`` and ``stats`` (``SchedStats``:
+    ``runnable`` and ``stepped`` summed over shards with ``psum``,
+    ``lanes`` the dispatch width of all shards, ``rounds`` the global
+    round count).  ``static`` holds the round-invariant arrays: the edge
+    list (pre, post, delay, w_ampa, w_gaba), the drive, the transport's
+    routing tables and the compact tables.  The example carry holds
+    shapes; ``static`` the concrete tables (shapes for the edge list and
+    the drive); the shardings are the jit's ``in_shardings``.
     """
     if transport != "allgather" and not optimized:
         raise ValueError("sparse transport realises the shard-local "
@@ -200,11 +218,12 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                                  wg.reshape(-1, k), valid.reshape(-1, k))
         return eq
 
-    def _round_local(sts, eq_t, eq_a, eq_g, pre_l, delay_l, wa_l, wg_l, iinj,
-                     *rest):
+    def _round_local(sts, eq_t, eq_a, eq_g, rec_t, rec_n, pre_l, delay_l,
+                     wa_l, wg_l, iinj, *rest):
         """One scheduler round on this shard's neurons.  All arrays are
         shard-local (the static tables replicated); the ONLY communication
-        is the transport's channels (plus the scalar telemetry psums)."""
+        is the transport's channels (plus the scalar telemetry psums).
+        Each stage runs under its ``exec_common`` stage scope."""
         from repro.kernels.event_wheel import ops as ew_ops
 
         n_carry = 3 if incremental else 0
@@ -214,8 +233,10 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
         n_loc = t_local.shape[0]
         sidx = shard_index(mesh, flat)
         offset = sidx * n_local
+        rec = ev.SpikeRecord(rec_t, rec_n, jnp.zeros((), jnp.int32))
         # --- notify: clock exchange (stepping notifications) --------------
-        t_table, bnd_new = tp.notify(t_local, *targs)
+        with jax.named_scope("fap.notify"):
+            t_table, bnd_new = tp.notify(t_local, *targs)
         # --- horizon + runnable (shared helper, shard-relative post) ------
         post_rel = jnp.repeat(jnp.arange(n_loc), spec.k_in)
         dloc = xc.DeviceNet(pre_l, post_rel, delay_l, wa_l, wg_l)
@@ -242,14 +263,6 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                 h = jnp.minimum(jnp.min(cand, axis=0), spec.t_end)
                 return jnp.minimum(h, t_local[pc] + spec.horizon_cap)
 
-            # moved set: frontier clocks that changed since last round
-            # (pad slots carry the gid sentinel n -> masked out) plus last
-            # round's locally advanced lanes (carried compact ids)
-            moved_b = jnp.logical_and(bnd_new != prev_bnd, b_gid_flat < n)
-            mids, mcnt = ew_ops.compact_ids(moved_b, mcap)
-            gids = jnp.where(mids < sf,
-                             b_gid_flat[jnp.minimum(mids, sf - 1)], n)
-
             def _incr_horizon(hor):
                 own = jnp.where(moved_prev < n_loc, moved_prev + offset, n)
                 srcs = jnp.concatenate([gids, own])
@@ -261,35 +274,63 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                 p = jnp.concatenate([moved_prev, p_loc.reshape(-1)])
                 return hor.at[p].set(_rows(p), mode="drop")
 
-            horizon = jax.lax.cond(mcnt <= mcap, _incr_horizon,
-                                   _full_horizon, hor_c)
+            with jax.named_scope("fap.horizon_update"):
+                # moved set: frontier clocks that changed since last round
+                # (pad slots carry the gid sentinel n -> masked out) plus
+                # last round's locally advanced lanes (carried compact ids)
+                moved_b = jnp.logical_and(bnd_new != prev_bnd, b_gid_flat < n)
+                mids, mcnt = ew_ops.compact_ids(moved_b, mcap)
+                gids = jnp.where(mids < sf,
+                                 b_gid_flat[jnp.minimum(mids, sf - 1)], n)
+                horizon = jax.lax.cond(mcnt <= mcap, _incr_horizon,
+                                       _full_horizon, hor_c)
             prev_bnd = bnd_new
+            with jax.named_scope("fap.horizon"):
+                runnable = xc.runnable_mask(t_local, horizon)
         else:
-            horizon = _full_horizon(None)
-        runnable = xc.runnable_mask(t_local, horizon)
+            with jax.named_scope("fap.horizon"):
+                horizon = _full_horizon(None)
+                runnable = xc.runnable_mask(t_local, horizon)
+        n_runnable = runnable.sum(dtype=jnp.int64)
         # --- advance (dense: all lanes; compact: the shard-local active
         # set, gathered into a fixed [cap] batch and scattered back) -------
         if batch == "compact":
-            ids, _ = xc.compact_frontier(runnable, t_local, cap)
-            lane_ok = ids < n_loc
-            idc = jnp.minimum(ids, n_loc - 1)
-            sts_b = xc.gather_lanes(sts, idc)
-            t_b_prev = sts_b.t
-            sts_b, eqt_b, spiked_b, tsp_b, nd, nrs = vadvance(
-                sts_b, eq_t[idc], eq_a[idc], eq_g[idc], horizon[idc],
-                lane_ok, iinj[idc])
-            sts = xc.scatter_lanes(sts, sts_b, ids)
-            eq_t = xc.scatter_at(eq_t, ids, eqt_b)
-            spiked = xc.scatter_at(jnp.zeros((n_loc,), bool), ids, spiked_b)
-            t_sp = xc.scatter_at(jnp.zeros((n_loc,)), ids, tsp_b)
+            with jax.named_scope("fap.compact"):
+                ids, _ = xc.compact_frontier(runnable, t_local, cap)
+            with jax.named_scope("fap.gather"):
+                lane_ok = ids < n_loc
+                idc = jnp.minimum(ids, n_loc - 1)
+                sts_b = xc.gather_lanes(sts, idc)
+                t_b_prev = sts_b.t
+                eqt_b, eqa_b, eqg_b = eq_t[idc], eq_a[idc], eq_g[idc]
+                hor_b, iinj_b = horizon[idc], iinj[idc]
+            with jax.named_scope("fap.advance"):
+                sts_b, eqt_b, spiked_b, tsp_b, nd, nrs = vadvance(
+                    sts_b, eqt_b, eqa_b, eqg_b, hor_b, lane_ok, iinj_b)
+            with jax.named_scope("fap.scatter"):
+                sts = xc.scatter_lanes(sts, sts_b, ids)
+                eq_t = xc.scatter_at(eq_t, ids, eqt_b)
+                spiked = xc.scatter_at(jnp.zeros((n_loc,), bool), ids,
+                                       spiked_b)
+                t_sp = xc.scatter_at(jnp.zeros((n_loc,)), ids, tsp_b)
+                rec = ev.record_spikes(rec, ids, tsp_b, spiked_b)
             if incremental:
-                moved = jnp.logical_and(lane_ok, sts_b.t != t_b_prev)
-                moved_prev = jnp.where(moved, ids, n_loc).astype(jnp.int32)
+                with jax.named_scope("fap.horizon_update"):
+                    moved = jnp.logical_and(lane_ok, sts_b.t != t_b_prev)
+                    moved_prev = jnp.where(moved, ids, n_loc).astype(
+                        jnp.int32)
+            n_stepped = lane_ok.sum(dtype=jnp.int64)
         else:
-            sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
-                sts, eq_t, eq_a, eq_g, horizon, runnable, iinj)
+            with jax.named_scope("fap.advance"):
+                sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
+                    sts, eq_t, eq_a, eq_g, horizon, runnable, iinj)
+            with jax.named_scope("fap.scatter"):
+                rec = ev.record_spikes(rec, jnp.arange(n_loc), t_sp, spiked)
+            n_stepped = n_runnable
         # --- parcel exchange ----------------------------------------------
-        spiked_all, tsp_all, pdrop, pbytes = tp.exchange(spiked, t_sp, *targs)
+        with jax.named_scope("fap.exchange"):
+            spiked_all, tsp_all, pdrop, pbytes = tp.exchange(spiked, t_sp,
+                                                             *targs)
 
         # --- insert (shard-local): dense = grouped scan of all E/n_shards
         # in-edges; compact = gather only the spiking set's out-edges that
@@ -299,35 +340,40 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
             t_ev = tsp_all[pre_l] + delay_l
             return _insert_byk(eq_t, eq_a, eq_g, t_ev, wa_l, wg_l, valid)
 
-        if fanout == "compact":
-            edge_tbl_r = tbls[0]
+        with jax.named_scope("fap.fanout"):
+            if fanout == "compact":
+                edge_tbl_r = tbls[0]
 
-            def _ins_compact(eq_t, eq_a, eq_g):
-                ids_s, eids, _ = ew_ops.compact_gather(
-                    spiked_all, edge_tbl_r, s_cap, fill=E)
-                idc_s = jnp.minimum(ids_s, n - 1)
-                le = eids - sidx * e_local
-                ok = jnp.logical_and(
-                    (ids_s < n)[:, None],
-                    jnp.logical_and(eids < E,
-                                    jnp.logical_and(le >= 0, le < e_local)))
-                lec = jnp.clip(le, 0, e_local - 1)
-                tgt = lec // spec.k_in          # shard-relative post (grouped)
-                t_ev = tsp_all[idc_s][:, None] + delay_l[lec]
-                eq = qops.wrap(eq_t, eq_a, eq_g, jnp.zeros((), jnp.int32))
-                return qops.insert_batch(
-                    eq, tgt.ravel(), t_ev.ravel(), wa_l[lec].ravel(),
-                    wg_l[lec].ravel(), ok.ravel())
+                def _ins_compact(eq_t, eq_a, eq_g):
+                    ids_s, eids, _ = ew_ops.compact_gather(
+                        spiked_all, edge_tbl_r, s_cap, fill=E)
+                    idc_s = jnp.minimum(ids_s, n - 1)
+                    le = eids - sidx * e_local
+                    ok = jnp.logical_and(
+                        (ids_s < n)[:, None],
+                        jnp.logical_and(eids < E, jnp.logical_and(
+                            le >= 0, le < e_local)))
+                    lec = jnp.clip(le, 0, e_local - 1)
+                    tgt = lec // spec.k_in      # shard-relative post (grouped)
+                    t_ev = tsp_all[idc_s][:, None] + delay_l[lec]
+                    eq = qops.wrap(eq_t, eq_a, eq_g,
+                                   jnp.zeros((), jnp.int32))
+                    return qops.insert_batch(
+                        eq, tgt.ravel(), t_ev.ravel(), wa_l[lec].ravel(),
+                        wg_l[lec].ravel(), ok.ravel())
 
-            eq = jax.lax.cond(spiked_all.sum() <= s_cap, _ins_compact,
-                              _ins_dense, eq_t, eq_a, eq_g)
-        else:
-            eq = _ins_dense(eq_t, eq_a, eq_g)
+                eq = jax.lax.cond(spiked_all.sum() <= s_cap, _ins_compact,
+                                  _ins_dense, eq_t, eq_a, eq_g)
+            else:
+                eq = _ins_dense(eq_t, eq_a, eq_g)
         nd = jax.lax.psum(nd.sum(), flat)
         nrs = jax.lax.psum(nrs.sum(), flat)
         dropped = jax.lax.psum(eq.dropped + pdrop, flat)
-        out = (sts, eq.t, eq.w_ampa, eq.w_gaba, spiked, t_sp, nd, nrs,
-               dropped, pbytes)
+        over = jax.lax.psum(rec.overflow, flat)
+        n_runnable = jax.lax.psum(n_runnable, flat)
+        n_stepped = jax.lax.psum(n_stepped, flat)
+        out = (sts, eq.t, eq.w_ampa, eq.w_gaba, rec.times, rec.count, over,
+               nd, nrs, dropped, pbytes, n_runnable, n_stepped)
         if incremental:
             out += (horizon, prev_bnd, moved_prev)
         return out
@@ -336,82 +382,266 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     # replicated (the all_gather output IS replicated), moved ids [S*cap]
     # sharded (each shard's own compact batch)
     carry_specs = (P(flat), P(None), P(flat)) if incremental else ()
+    # lanes dispatched a round, all shards together
+    lanes = n_shards * cap if batch == "compact" else n
 
-    def fap_round(sts, eq_t, eq_a, eq_g, pre, post, delay, w_a, w_g, iinj,
-                  *rest):
+    def fap_round(sc, static):
+        pre, post, delay, w_a, w_g, iinj = static[:6]
+        eq_t, eq_a, eq_g = sc.eq
+        rec = sc.rec
         if optimized:
             # per-leaf specs: leading neuron dim sharded over every axis
             sts_specs = jax.tree_util.tree_map(
-                lambda leaf: P(flat, *([None] * (leaf.ndim - 1))), sts)
+                lambda leaf: P(flat, *([None] * (leaf.ndim - 1))), sc.sts)
             n2 = P(flat, None)
             fn_l = jax.shard_map(
                 _round_local, mesh=mesh,
-                in_specs=(sts_specs, n2, n2, n2, P(flat), P(flat), P(flat),
-                          P(flat), P(flat)) + carry_specs + tp.in_specs
-                + tbl_specs,
-                out_specs=(sts_specs, n2, n2, n2, P(flat), P(flat), P(), P(),
-                           P(), P()) + carry_specs,
+                in_specs=(sts_specs, n2, n2, n2, n2, P(flat), P(flat),
+                          P(flat), P(flat), P(flat), P(flat)) + carry_specs
+                + tp.in_specs + tbl_specs,
+                out_specs=(sts_specs, n2, n2, n2, n2, P(flat))
+                + (P(),) * 7 + carry_specs,
                 check_vma=False)
-            return fn_l(sts, eq_t, eq_a, eq_g, pre, delay, w_a, w_g, iinj,
-                        *rest)
-        t_clock = sts.t
-        dnet = xc.DeviceNet(pre, post, delay, w_a, w_g)
-        horizon = xc.horizon_times(dnet, n, t_clock, spec.t_end,
-                                   horizon_cap=spec.horizon_cap)
-        runnable = xc.runnable_mask(t_clock, horizon)
-        sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
-            sts, eq_t, eq_a, eq_g, horizon, runnable, iinj)
-        valid = spiked[pre]
-        t_ev = t_sp[pre] + delay
-        # the global path honours the runtime `post` array (arbitrary edge
-        # order); both queue impls insert to explicit targets sort-free or
-        # not per their contract
-        eq = qops.wrap(eq_t, eq_a, eq_g, jnp.zeros((), jnp.int32))
-        eq = qops.insert(eq, post, t_ev, w_a, w_g, valid)
-        return (sts, eq.t, eq.w_ampa, eq.w_gaba, spiked, t_sp, nd.sum(),
-                nrs.sum(), eq.dropped, jnp.zeros((), jnp.int32))
+            (sts, eq_t, eq_a, eq_g, rec_t, rec_n, over, nd, nrs, dropped,
+             pbytes, n_runnable, n_stepped, *hc) = fn_l(
+                sc.sts, eq_t, eq_a, eq_g, rec.times, rec.count, pre, delay,
+                w_a, w_g, iinj, *sc.hcarry, *static[6:])
+            rec = ev.SpikeRecord(rec_t, rec_n, rec.overflow + over)
+        else:
+            t_clock = sc.sts.t
+            dnet = xc.DeviceNet(pre, post, delay, w_a, w_g)
+            horizon = xc.horizon_times(dnet, n, t_clock, spec.t_end,
+                                       horizon_cap=spec.horizon_cap)
+            runnable = xc.runnable_mask(t_clock, horizon)
+            sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
+                sc.sts, eq_t, eq_a, eq_g, horizon, runnable, iinj)
+            rec = ev.record_spikes(rec, jnp.arange(n), t_sp, spiked)
+            valid = spiked[pre]
+            t_ev = t_sp[pre] + delay
+            # the global path honours the runtime `post` array (arbitrary
+            # edge order); both queue impls insert to explicit targets
+            # sort-free or not per their contract
+            eq = qops.wrap(eq_t, eq_a, eq_g, jnp.zeros((), jnp.int32))
+            eq = qops.insert(eq, post, t_ev, w_a, w_g, valid)
+            eq_t, eq_a, eq_g = eq.t, eq.w_ampa, eq.w_gaba
+            nd, nrs, dropped = nd.sum(), nrs.sum(), eq.dropped
+            pbytes = jnp.zeros((), jnp.int32)
+            n_runnable = n_stepped = runnable.sum(dtype=jnp.int64)
+            hc = ()
+        c = sc.counters
+        st = c["stats"]
+        stats = st._replace(runnable=st.runnable + n_runnable,
+                            stepped=st.stepped + n_stepped,
+                            lanes=st.lanes + lanes, rounds=st.rounds + 1)
+        return xc.SimCarry(sts, (eq_t, eq_a, eq_g), rec, tuple(hc), {
+            "n_ev": c["n_ev"] + nd, "n_rs": c["n_rs"] + nrs,
+            "dropped": c["dropped"] + dropped,
+            "parcel_bytes": c["parcel_bytes"] + pbytes,
+            "rounds": c["rounds"] + 1, "stats": stats})
 
     # ---- example args (ShapeDtypeStructs) and shardings -------------------
-    f8 = jnp.float64
+    f8, i4, i8 = jnp.float64, jnp.int32, jnp.int64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
     sts = jax.eval_shape(
         lambda: jax.vmap(lambda i: bdf.reinit(
             model, 0.0, model.init_state(), i, opts))(jnp.zeros((n,), f8)))
-    args = (
-        sts,
-        jax.ShapeDtypeStruct((n, qcap), f8),           # eq_t
-        jax.ShapeDtypeStruct((n, qcap), f8),           # eq_a
-        jax.ShapeDtypeStruct((n, qcap), f8),           # eq_g
-        jax.ShapeDtypeStruct((E,), jnp.int32),         # pre
-        jax.ShapeDtypeStruct((E,), jnp.int32),         # post
-        jax.ShapeDtypeStruct((E,), f8),                # delay
-        jax.ShapeDtypeStruct((E,), f8),                # w_ampa
-        jax.ShapeDtypeStruct((E,), f8),                # w_gaba
-        jax.ShapeDtypeStruct((n,), f8),                # iinj
-    )
-    carry_args = ()
+    hcarry = ()
     if incremental:
-        carry_args = (
-            jax.ShapeDtypeStruct((n,), f8),                      # horizon
-            jax.ShapeDtypeStruct((sf_len,), f8),                 # prev_bnd
-            jax.ShapeDtypeStruct((n_shards * cap,), jnp.int32),  # moved ids
-        )
-    args = args + carry_args + \
-        (tp.example_args if tp is not None else ()) + tbl_args
+        hcarry = (sds((n,), f8),                       # horizon
+                  sds((sf_len,), f8),                  # prev boundary clocks
+                  sds((n_shards * cap,), i4))          # moved ids
+    carry = xc.SimCarry(
+        sts, (sds((n, qcap), f8),) * 3,                # queue t, w_a, w_g
+        ev.SpikeRecord(sds((n, spk_cap), f8), sds((n,), i4), sds((), i4)),
+        hcarry, {"n_ev": sds((), i8), "n_rs": sds((), i8),
+                 "dropped": sds((), i8), "parcel_bytes": sds((), i8),
+                 "rounds": sds((), i4),
+                 "stats": jax.eval_shape(xc.SchedStats.zeros)})
+    static = (sds((E,), i4), sds((E,), i4), sds((E,), f8), sds((E,), f8),
+              sds((E,), f8), sds((n,), f8)) \
+        + (tp.example_args if tp is not None else ()) + tbl_args
 
-    def st_spec(leaf):
-        return NamedSharding(mesh, P(flat, *([None] * (leaf.ndim - 1))))
+    def named(spec_):
+        return NamedSharding(mesh, spec_)
 
+    rows, repl = named(P(flat)), named(P())
     sts_sh = jax.tree_util.tree_map(
-        lambda leaf: st_spec(leaf) if leaf.ndim >= 1 else NamedSharding(mesh, P()),
-        sts)
-    esh = NamedSharding(mesh, nshard)
-    n2 = NamedSharding(mesh, P(flat, None))
-    carry_sh = tuple(NamedSharding(mesh, s) for s in carry_specs)
-    in_shardings = (sts_sh, n2, n2, n2, esh, esh, esh, esh, esh,
-                    NamedSharding(mesh, nshard)) + carry_sh + \
-        (tp.shardings if tp is not None else ()) + \
-        tuple(NamedSharding(mesh, s) for s in tbl_specs)
-    return fap_round, args, in_shardings
+        lambda leaf: named(P(flat, *([None] * (leaf.ndim - 1))))
+        if leaf.ndim >= 1 else repl, sts)
+    n2 = named(P(flat, None))
+    carry_sh = xc.SimCarry(
+        sts_sh, (n2,) * 3, ev.SpikeRecord(n2, rows, repl),
+        tuple(named(s_) for s_ in carry_specs),
+        jax.tree_util.tree_map(lambda _: repl, carry.counters))
+    static_sh = (rows,) * 6 + (tp.shardings if tp is not None else ()) \
+        + tuple(named(s_) for s_ in tbl_specs)
+    fap_round.batch_cap, fap_round.spike_cap = cap, s_cap    # caps in force
+    return fap_round, (carry, static), (carry_sh, static_sh)
+
+
+def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
+                         opts: bdf.BDFOptions = bdf.BDFOptions(),
+                         optimized: bool = True, queue: str = "dense",
+                         wheel: sched.WheelSpec = sched.WheelSpec(),
+                         transport: str = "allgather",
+                         exchange: ExchangeSpec = ExchangeSpec(),
+                         ev_cap: int = 32, horizon_cap: float = 2.0,
+                         max_rounds: int = 1_000_000, spk_cap: int = 128,
+                         batch: str = "dense", batch_cap: int = 0,
+                         fanout: str = "dense", spike_cap: int = 0,
+                         horizon: str = "full", move_cap: int = 0):
+    """The SPMD round as a runner, the surface ``exec_fap``'s runners give:
+    ``run.init_carry()`` (round 0, placed on the mesh), ``run.round_body``
+    (carry -> carry, jittable: one round of ``build_fap_round``, spikes
+    recorded inside it), ``run.cond`` (on the device: GVT < t_end, no
+    solver failure, fewer than ``max_rounds`` rounds) and ``run.pack``
+    (carry -> ``SimCarry``; the carry already is one).  Calling
+    ``run(...)`` drives ``round_body`` host-stepped through
+    ``exec_common.run_checkpointed`` (checkpoints, faults, the watchdog;
+    see ``run_fap_spmd``) and returns (RunResult, rounds).
+
+    ``net`` must have ``make_network``'s grouped by-post edge layout; the
+    keyword arguments are ``build_fap_round``'s (``spk_cap``: spikes
+    recorded a neuron).  The round-invariant arrays (``run.static``) are
+    placed on the mesh once; ``round_body`` closes over them and
+    ``run.round_with(carry, static)`` takes them as arguments.
+    ``run.batch_cap`` / ``run.spike_cap`` are the caps in force,
+    ``run.n_local`` the neurons a shard holds."""
+    n = int(net.n)
+    k = sched.grouped_k(net)
+    if k is None:
+        raise ValueError("the SPMD round needs make_network's grouped by-post "
+                         "edge layout")
+    incremental = horizon == "incremental"
+    n_shards = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
+    spec = PaperNeuroSpec(n_neurons=n, k_in=k, ev_cap=ev_cap, t_end=t_end,
+                          horizon_cap=horizon_cap)
+    fn, (ex, ex_static), (carry_sh, static_sh) = build_fap_round(
+        model, spec, mesh, opts, optimized=optimized, queue=queue,
+        wheel=wheel, transport=transport, exchange=exchange, net=net,
+        batch=batch, batch_cap=batch_cap, fanout=fanout,
+        spike_cap=spike_cap, horizon=horizon, move_cap=move_cap,
+        spk_cap=spk_cap)
+    qops = sched.get_queue_ops(queue, ev_cap=ev_cap, wheel=wheel)
+    dnet = xc.to_device(net)
+    iinj_v = jnp.broadcast_to(jnp.asarray(iinj, jnp.float64), (n,))
+    # round-invariant arrays placed once with the round's shardings (the
+    # loop then pays the two transport channels only, no resharding)
+    static = jax.device_put(
+        (dnet.pre, dnet.post, dnet.delay, dnet.w_ampa, dnet.w_gaba, iinj_v)
+        + tuple(ex_static[6:]), static_sh)
+    n_local = n // n_shards
+
+    def seed_hcarry(clocks, static):
+        """(horizon, prev boundary clocks, moved ids) seeded from a clock
+        vector: a full-recompute horizon (which the incremental chain
+        equals bitwise — min is exact), all-zero previous boundary clocks
+        (every frontier entry looks moved next round -> extra or full
+        recompute, still exact) and sentinel moved ids.  Round 0 and
+        elastic resume share this."""
+        hor0 = xc.horizon_times(xc.DeviceNet(*static[:5]), n, clocks, t_end,
+                                horizon_cap=horizon_cap)
+        return (hor0, jnp.zeros(ex.hcarry[1].shape, jnp.float64),
+                jnp.full(ex.hcarry[2].shape, n_local, jnp.int32))
+
+    def _init(static):
+        iv = static[5]
+        Y = xc.batch_init(model, n)
+        sts = jax.vmap(lambda y, i: bdf.reinit(model, 0.0, y, i, opts))(
+            Y, iv)
+        eq = qops.make(n)
+        hcarry = seed_hcarry(jnp.zeros((n,), jnp.float64), static) \
+            if incremental else ()
+        z64 = jnp.zeros((), jnp.int64)
+        return xc.SimCarry(sts, (eq.t, eq.w_ampa, eq.w_gaba),
+                           ev.make_spike_record(n, spk_cap), hcarry,
+                           {"n_ev": z64, "n_rs": z64, "dropped": z64,
+                            "parcel_bytes": z64,
+                            "rounds": jnp.zeros((), jnp.int32),
+                            "stats": xc.SchedStats.zeros()})
+
+    # placed as the round returns them: an unplaced carry is another input
+    # type, and the first round would compile the round a second time
+    jinit = jax.jit(_init, out_shardings=carry_sh)
+    jseed = jax.jit(seed_hcarry, out_shardings=carry_sh.hcarry)
+
+    def init_carry():
+        return jinit(static)
+
+    def round_body(sc):
+        return fn(sc, static)
+
+    def cond(sc):
+        return jnp.logical_and(
+            sc.sts.t.min() < t_end - 1e-9,
+            jnp.logical_and(sc.counters["rounds"] < max_rounds,
+                            ~sc.sts.failed.any()))
+
+    jround = jcond = None
+
+    def run(checkpoint_every: int = 0, ckpt_dir=None, resume: bool = False,
+            fault=None, watchdog: bool = True, max_rollbacks: int = 2,
+            ckpt_keep: int = 3):
+        """Host-stepped drive of ``round_body`` (one compile, reused by
+        every call): ``run_fap_spmd``'s robustness knobs."""
+        from repro.core.exec_bsp import RunResult
+        nonlocal jround, jcond
+        if jround is None:
+            jround = jax.jit(fn, in_shardings=(carry_sh, static_sh))
+            jcond = jax.jit(cond)
+
+        def health_of(sc, t_prev):
+            return xc.health_check(
+                sc.sts, t_prev, horizon=sc.hcarry[0] if incremental else None,
+                horizon_cap=horizon_cap)
+
+        # layout fingerprint: a resume whose mesh/transport layout changed
+        # must reseed the shard-relative hcarry even when its widths
+        # coincide
+        fingerprint = {"mesh_shape": [int(mesh.shape[a])
+                                      for a in mesh.axis_names],
+                       "transport": transport, "batch_cap": int(batch_cap),
+                       "horizon": horizon} if incremental else None
+        sc, health = xc.run_checkpointed(
+            init_carry, lambda sc: jround(sc, static),
+            lambda sc: bool(jcond(sc)), ckpt_dir=ckpt_dir,
+            checkpoint_every=checkpoint_every, resume=resume,
+            keep=ckpt_keep, fault=fault,
+            health_of=health_of if watchdog else None,
+            max_rollbacks=max_rollbacks, shardings=carry_sh,
+            fingerprint=fingerprint,
+            reseed=(lambda sc: sc._replace(hcarry=jseed(sc.sts.t, static)))
+            if incremental else None)
+        sts = sc.sts
+        rounds = int(sc.counters["rounds"])
+        health["dropped_events"] = int(sc.counters["dropped"])
+        # a run cut by max_rounds before t_end says so
+        health["max_rounds_reached"] = bool(
+            rounds >= max_rounds and float(sts.t.min()) < t_end - 1e-9)
+        res = RunResult(sc.rec, sts.nst.sum(),
+                        jnp.asarray(sc.counters["n_ev"], jnp.int32),
+                        jnp.asarray(sc.counters["n_rs"], jnp.int32),
+                        jnp.asarray(sc.counters["dropped"], jnp.int32),
+                        jnp.logical_or(sts.failed.any(),
+                                       health["rollback_exhausted"]),
+                        sts.zn[:, 0], sched=sc.counters["stats"],
+                        comm={"parcel_bytes": int(sc.counters[
+                            "parcel_bytes"]), "rounds": rounds},
+                        solver=xc.solver_stats(sts), health=health)
+        return res, rounds
+
+    run.init_carry = init_carry
+    run.round_body = round_body
+    run.round_with = fn
+    run.cond = cond
+    run.pack = lambda sc: sc
+    run.static = static
+    run.batch_cap, run.spike_cap = fn.batch_cap, fn.spike_cap
+    run.n_local = n_local
+    return run
 
 
 def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
@@ -421,17 +651,20 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
                  transport: str = "allgather",
                  exchange: ExchangeSpec = ExchangeSpec(),
                  ev_cap: int = 32, horizon_cap: float = 2.0,
-                 max_rounds: int = 400, spk_cap: int = 128,
+                 max_rounds: int = 1_000_000, spk_cap: int = 128,
                  placement=None, batch: str = "dense", batch_cap: int = 0,
                  fanout: str = "dense", spike_cap: int = 0,
                  horizon: str = "full", move_cap: int = 0,
                  checkpoint_every: int = 0, ckpt_dir=None,
                  resume: bool = False, fault=None, watchdog: bool = True,
                  max_rollbacks: int = 2, ckpt_keep: int = 3):
-    """Drive the SPMD round to t_end on a concrete network; the host loop
-    records spike trains and accumulates the per-round telemetry into the
-    standard ``RunResult`` (dropped = queue + parcel overflow — detected,
-    never silent).  Returns (RunResult, rounds).
+    """Drive the SPMD round to t_end on a concrete network: the round of
+    ``make_fap_spmd_runner``, host-stepped, each round recording its spikes
+    and accumulating its telemetry into the standard ``RunResult``
+    (dropped = queue + parcel overflow — detected, never silent).  Returns
+    (RunResult, rounds).  A run that ``max_rounds`` (by default the
+    single-host runners' 1,000,000) cuts before ``t_end`` reports
+    ``health["max_rounds_reached"]``.
 
     placement: optional ``distributed.placement.Placement`` (or a method
     name for ``compute_placement``) — the neuron-id relabeling is applied
@@ -444,10 +677,10 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
     to ``build_fap_round`` — "compact" runs the shard-local advance
     (delivery) on the compacted runnable (spiking) set only, and
     horizon="incremental" carries the dependency horizon across rounds
-    recomputing only frontier-fed rows (``RunResult.sched`` telemetry is
-    not collected on the SPMD path; ``RunResult.comm`` records the
-    realized parcel bytes summed over rounds — with the ragged transport
-    this is the per-round class choice made visible).
+    recomputing only frontier-fed rows.  ``RunResult.sched`` holds the
+    round's ``SchedStats`` (summed over shards); ``RunResult.comm`` the
+    parcel bytes each device shipped, summed over rounds — with the
+    ragged transport the per-round class choice made visible.
 
     Preemption tolerance (exec_common.run_checkpointed):
     checkpoint_every=k snapshots the full round state (``SimCarry``) into
@@ -464,8 +697,6 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
     max_rollbacks, then ``RunResult.failed``); telemetry lands on
     ``RunResult.health``.
     """
-    from repro.core import events as ev
-    from repro.core.exec_bsp import RunResult
     from repro.distributed import placement as plc
 
     pl = None
@@ -474,126 +705,15 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
         pl = placement if isinstance(placement, plc.Placement) else \
             plc.compute_placement(net, n_shards, method=placement)
         net, iinj = plc.place_inputs(net, iinj, pl)
-    n = int(net.n)
-    k = sched.grouped_k(net)
-    if k is None:
-        raise ValueError("run_fap_spmd needs make_network's grouped by-post "
-                         "edge layout")
-    spec = PaperNeuroSpec(n_neurons=n, k_in=k, ev_cap=ev_cap, t_end=t_end,
-                          horizon_cap=horizon_cap)
-    fn, ex_args, in_sh = build_fap_round(model, spec, mesh, opts,
-                                         optimized=optimized, queue=queue,
-                                         wheel=wheel, transport=transport,
-                                         exchange=exchange, net=net,
-                                         batch=batch, batch_cap=batch_cap,
-                                         fanout=fanout, spike_cap=spike_cap,
-                                         horizon=horizon, move_cap=move_cap)
-    qops = sched.get_queue_ops(queue, ev_cap=ev_cap, wheel=wheel)
-    iinj_v = jnp.broadcast_to(jnp.asarray(iinj, jnp.float64), (n,))
-    dnet = xc.to_device(net)
-    n_carry = 3 if horizon == "incremental" else 0
-    # round-invariant args placed once with the build's shardings (the loop
-    # then pays the two transport channels only, no per-round resharding)
-    static = jax.device_put(
-        (dnet.pre, dnet.post, dnet.delay, dnet.w_ampa, dnet.w_gaba, iinj_v)
-        + ex_args[10 + n_carry:],
-        in_sh[4:10] + in_sh[10 + n_carry:])
-
-    def seed_hcarry(clocks):
-        """(horizon, prev boundary clocks, moved ids) seeded from a clock
-        vector: a full-recompute horizon (which the incremental chain
-        equals bitwise — min is exact), all-zero previous boundary clocks
-        (every frontier entry looks moved next round -> extra or full
-        recompute, still exact) and sentinel moved ids.  Round 0 and
-        elastic resume share this."""
-        hor0 = xc.horizon_times(dnet, n, clocks, t_end,
-                                horizon_cap=horizon_cap)
-        prev0 = jnp.zeros(ex_args[11].shape, jnp.float64)  # boundary clocks
-        moved0 = jnp.full(ex_args[12].shape, n // int(np.prod(
-            [mesh.shape[a] for a in mesh.axis_names])), jnp.int32)
-        return tuple(jax.device_put((hor0, prev0, moved0), in_sh[10:13]))
-
-    jfn = jax.jit(fn, in_shardings=in_sh)
-    neuron_ids = jnp.arange(n, dtype=jnp.int32)    # hoisted round constant
-    repl = NamedSharding(mesh, P())
-    z64 = jnp.zeros((), jnp.int64)
-
-    def init_fn():
-        Y = xc.batch_init(model, n)
-        sts = jax.vmap(lambda y, i: bdf.reinit(model, 0.0, y, i, opts))(
-            Y, iinj_v)
-        eq = qops.make(n)
-        # placed as the round returns them: an unplaced carry is another
-        # input type, and round 0 would compile the round a second time
-        sts, eqs = jax.device_put((sts, (eq.t, eq.w_ampa, eq.w_gaba)),
-                                  (in_sh[0], tuple(in_sh[1:4])))
-        hcarry = seed_hcarry(jnp.zeros((n,), jnp.float64)) if n_carry else ()
-        rec = ev.make_spike_record(n, spk_cap)
-        return xc.SimCarry(sts, eqs, rec, hcarry,
-                           {"n_ev": z64, "n_rs": z64, "dropped": z64,
-                            "parcel_bytes": z64,
-                            "rounds": jnp.zeros((), jnp.int32)})
-
-    def step_fn(sc):
-        eq_t, eq_a, eq_g = sc.eq
-        out = jfn(sc.sts, eq_t, eq_a, eq_g, *static[:6], *sc.hcarry,
-                  *static[6:])
-        (sts, eq_t, eq_a, eq_g, spiked, t_sp, nd, nrs, dropped,
-         pbytes) = out[:10]
-        rec = ev.record_spikes(sc.rec, neuron_ids, t_sp, spiked)
-        c = sc.counters
-        return xc.SimCarry(sts, (eq_t, eq_a, eq_g), rec, out[10:], {
-            "n_ev": c["n_ev"] + nd, "n_rs": c["n_rs"] + nrs,
-            "dropped": c["dropped"] + dropped,
-            "parcel_bytes": c["parcel_bytes"] + pbytes,
-            "rounds": c["rounds"] + 1})
-
-    def cond_fn(sc):
-        return (int(sc.counters["rounds"]) < max_rounds
-                and float(sc.sts.t.min()) < t_end - 1e-9
-                and not bool(sc.sts.failed.any()))
-
-    # SimCarry-shaped sharding tree for restore: the build's input
-    # shardings where they exist, replicated for the host-side leaves
-    # (spike record + counters) — the elastic-resume device_put path
-    sh_tree = xc.SimCarry(
-        in_sh[0], in_sh[1:4],
-        jax.tree_util.tree_map(lambda _: repl, ev.make_spike_record(1, 1)),
-        tuple(in_sh[10:13]) if n_carry else (),
-        {k_: repl for k_ in ("n_ev", "n_rs", "dropped", "parcel_bytes",
-                             "rounds")})
-
-    def health_of(sc, t_prev):
-        return xc.health_check(
-            sc.sts, t_prev, horizon=sc.hcarry[0] if n_carry else None,
-            horizon_cap=horizon_cap)
-
-    # layout fingerprint: a resume whose mesh/transport layout changed must
-    # reseed the shard-relative hcarry even when its widths coincide
-    fingerprint = {"mesh_shape": [int(mesh.shape[a]) for a in mesh.axis_names],
-                   "transport": transport, "batch_cap": int(batch_cap),
-                   "horizon": horizon} if n_carry else None
-    sc, health = xc.run_checkpointed(
-        init_fn, step_fn, cond_fn, ckpt_dir=ckpt_dir,
-        checkpoint_every=checkpoint_every, resume=resume, keep=ckpt_keep,
-        fault=fault, health_of=health_of if watchdog else None,
-        max_rollbacks=max_rollbacks, shardings=sh_tree,
-        fingerprint=fingerprint,
-        reseed=(lambda sc: sc._replace(hcarry=seed_hcarry(sc.sts.t)))
-        if n_carry else None)
-    sts = sc.sts
-    rounds = int(sc.counters["rounds"])
-    health["dropped_events"] = int(sc.counters["dropped"])
-    res = RunResult(sc.rec, sts.nst.sum(),
-                    jnp.asarray(sc.counters["n_ev"], jnp.int32),
-                    jnp.asarray(sc.counters["n_rs"], jnp.int32),
-                    jnp.asarray(sc.counters["dropped"], jnp.int32),
-                    jnp.logical_or(sts.failed.any(),
-                                   health["rollback_exhausted"]),
-                    sts.zn[:, 0],
-                    comm={"parcel_bytes": int(sc.counters["parcel_bytes"]),
-                          "rounds": rounds},
-                    solver=xc.solver_stats(sts), health=health)
+    run = make_fap_spmd_runner(
+        model, net, iinj, t_end, mesh, opts, optimized=optimized,
+        queue=queue, wheel=wheel, transport=transport, exchange=exchange,
+        ev_cap=ev_cap, horizon_cap=horizon_cap, max_rounds=max_rounds,
+        spk_cap=spk_cap, batch=batch, batch_cap=batch_cap, fanout=fanout,
+        spike_cap=spike_cap, horizon=horizon, move_cap=move_cap)
+    res, rounds = run(checkpoint_every=checkpoint_every, ckpt_dir=ckpt_dir,
+                      resume=resume, fault=fault, watchdog=watchdog,
+                      max_rollbacks=max_rollbacks, ckpt_keep=ckpt_keep)
     if pl is not None:
         res = plc.unpermute_result(res, pl)
     return res, rounds

@@ -182,6 +182,9 @@ def to_named(tree_of_specs, mesh):
 # ---------------------------------------------------------------------------
 # neuro workload: static shard-frontier routing tables (sparse transport)
 # ---------------------------------------------------------------------------
+LANES = 128          # a frontier wider than this is padded to whole rows
+
+
 class ShardFrontier(NamedTuple):
     """Static routing tables for the sparse exchange, one row per shard.
 
@@ -222,6 +225,11 @@ def shard_frontier(pre: np.ndarray, post: np.ndarray, n: int,
     frontier, and hence the sparse transport's notify bytes, then shrink
     with whatever locality the placement realizes.  The returned tables
     are in *new* (placed) ids, matching a run on the placed network.
+
+    A frontier wider than one 128-lane row is padded with sentinel slots
+    to whole rows (never past the shard's ``n_local``), so networks of one
+    size whose widest boundary sets differ by a few neurons share the
+    round's shapes, and so one executable.
     """
     if n % n_shards:
         raise ValueError(f"n={n} not divisible by n_shards={n_shards}")
@@ -244,6 +252,8 @@ def shard_frontier(pre: np.ndarray, post: np.ndarray, n: int,
     sizes = np.bincount(bnd[:, 0], minlength=n_shards) if bnd.size else \
         np.zeros(n_shards, np.int64)
     F = max(1, int(sizes.max()) if sizes.size else 1)
+    if F > LANES:
+        F = min(n_local, -(-F // LANES) * LANES)
     boundary_gid = np.full((n_shards, F), n, np.int32)
     boundary_rel = np.zeros((n_shards, F), np.int32)
     for s in range(n_shards):
