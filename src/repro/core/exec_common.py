@@ -134,7 +134,10 @@ class SchedStats(NamedTuple):
     lanes (their cap terms) and the real out-edge slots of moved lanes,
     which the round's push writes, of the ``batch_cap * (1 +
     max_out_degree)`` it could; over ``rounds`` times that width it is the
-    stage's useful share (0 where the horizon is recomputed whole).
+    stage's useful share (0 where the horizon is recomputed whole).  The
+    SPMD round sums it over shards: the lanes, the slots of moved clocks
+    that land in each shard, and the whole table in a round that
+    rebuilds it.
     ``fanout_rounds`` counts the compact FAP rounds whose spike fan-out +
     insert ran (those with a spike: the ``lax.cond`` guard); over
     ``rounds`` it is the insert's duty share.  It stays 0 on the dense FAP
@@ -187,36 +190,39 @@ def select_active(runnable, t_clock, cap: int, n_iters: int = 48):
     return jnp.logical_and(runnable, score <= tau)
 
 
-def out_tables(net):
-    """Host-side by-pre grouping of the static edge list: per neuron i the
-    postsynaptic targets (sentinel N) and edge-list positions (sentinel E)
-    of its out-edges, padded to the max out-degree.  Builders that need
-    both tables (SPMD incremental horizon + compact fan-out) call it once —
-    the O(E log E) grouping is the expensive part, not the two views."""
-    pre = np.asarray(net.pre)
-    post = np.asarray(net.post)
-    n, E = int(net.n), int(pre.shape[0])
-    deg = np.bincount(pre, minlength=n)
-    mo = int(deg.max()) if E else 1
-    order = np.argsort(pre, kind="stable")
-    starts = np.zeros(n + 1, np.int64)
-    starts[1:] = np.cumsum(deg)
-    rank_in_pre = np.arange(E) - starts[pre[order]]
-    post_t = np.full((n, mo), n, np.int32)
-    post_t[pre[order], rank_in_pre] = post[order]
-    edge_t = np.full((n, mo), E, np.int32)
-    edge_t[pre[order], rank_in_pre] = order
-    return post_t, edge_t
-
-
 def in_edge_slots(edge_t, n: int, k: int) -> np.ndarray:
-    """Host-side map of ``out_tables``' edge table onto the [K, N] by-post
+    """Host-side map of ``out_edge_table`` onto the [K, N] by-post
     layout of a grouped net (``ops.by_post_layout``): edge ``e = j * K +
     k`` sits at flat slot ``k * N + j``; the padding E stays E (= K * N).
     The incremental horizon pushes a moved clock to these slots."""
     e = np.asarray(edge_t, np.int64)
     E = n * k
     return np.where(e < E, (e % k) * n + e // k, E).astype(np.int32)
+
+
+def in_clock_horizon(tin, delay_flat, t_clock, t_end, horizon_cap):
+    """The dependency horizon from an in-edge clock table ``tin`` (the
+    [K, n] table ``t[pre_byk]`` stored row-major as [K * n], ``delay_flat``
+    the delays in the same layout): the same adds and exact mins as the
+    full scatter-min of ``horizon_times``, so bitwise equal.  The min over
+    K folds the table's K rows elementwise."""
+    n = t_clock.shape[0]
+    cand = tin + delay_flat
+    hor = cand[:n]
+    for k in range(1, cand.shape[0] // n):
+        hor = jnp.minimum(hor, cand[k * n:(k + 1) * n])
+    hor = jnp.minimum(hor, t_end)
+    return jnp.minimum(hor, t_clock + horizon_cap)
+
+
+def push_clocks(tin, slots, vals):
+    """Write clock ``vals[i]`` into the in-edge clock table slots of row i
+    of ``slots``; a slot past the table (padding, a lane that did not
+    move, an edge of another shard) drops.  Returns the table and the
+    number of slots written."""
+    vals = jnp.broadcast_to(vals[:, None], slots.shape)
+    return (tin.at[slots].set(vals, mode="drop"),
+            (slots < tin.shape[0]).sum(dtype=jnp.int64))
 
 
 def out_edge_table(net) -> np.ndarray:
@@ -226,9 +232,20 @@ def out_edge_table(net) -> np.ndarray:
     The compact fan-out path (``fanout="compact"``) gathers these rows for
     the <= spike_cap spiking lanes and inserts only that
     [spike_cap, max_out_degree] edge batch — O(spikes * k_out) per
-    spiking round instead of the O(E) full fan-out.
+    spiking round instead of the O(E) full fan-out; the incremental
+    horizons push a moved clock to the in-edge slots of its row.
     """
-    return out_tables(net)[1]
+    pre = np.asarray(net.pre)
+    n, E = int(net.n), int(pre.shape[0])
+    deg = np.bincount(pre, minlength=n)
+    mo = int(deg.max()) if E else 1
+    order = np.argsort(pre, kind="stable")
+    starts = np.zeros(n + 1, np.int64)
+    starts[1:] = np.cumsum(deg)
+    rank_in_pre = np.arange(E) - starts[pre[order]]
+    edge_t = np.full((n, mo), E, np.int32)
+    edge_t[pre[order], rank_in_pre] = order
+    return edge_t
 
 
 def make_spike_insert(net, dnet: DeviceNet, qops, qinsert,
@@ -265,8 +282,8 @@ def make_spike_insert(net, dnet: DeviceNet, qops, qinsert,
     from repro.kernels.event_wheel import ops as ew_ops
     n, E = int(net.n), int(dnet.pre.shape[0])
     cap = min(int(spike_cap), n) if spike_cap > 0 else min(n, 256)
-    # [N, MO], sentinel E (edge_table lets builders that also need the
-    # out-post table share one out_tables() grouping pass)
+    # [N, MO], sentinel E (edge_table lets a caller that also maps the
+    # table onto in-edge slots share one grouping pass)
     edge_tbl = jnp.asarray(out_edge_table(net) if edge_table is None
                            else edge_table)
 
@@ -415,11 +432,11 @@ class SimCarry(NamedTuple):
 
     ``hcarry`` holds the incremental-horizon carry where a runner
     maintains one (``exec_fap``: the in-edge clock table; the SPMD round:
-    horizon, previous boundary clocks, moved ids) — the only layout- and
-    mesh-shape-*dependent* leaves.  On elastic resume onto a different
-    mesh shape ``restore_sim_checkpoint`` skips exactly these and the
-    caller reseeds them from the restored clocks (a full recompute, which
-    the incremental scheme equals bitwise because min is exact).
+    each shard's in-edge clock table, previous boundary clocks, moved
+    ids) — the only layout- and mesh-shape-*dependent* leaves.  On
+    elastic resume onto a different mesh shape ``restore_sim_checkpoint``
+    skips exactly these and the caller reseeds them from the restored
+    clocks (a full rebuild, which the pushed table equals bitwise).
     """
     sts: object        # batched bdf.BDFState pytree [N, ...]
     eq: object         # queue pytree (EventQueue / WheelQueue / (t, wa, wg))

@@ -225,7 +225,7 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
         pre_byk, delay_byk = ew_ops.by_post_layout(net)
         # one grouping pass serves the push ([N, MO] slots of the in-edge
         # clock table) and the compact fan-out's edge table
-        _, edge_np = xc.out_tables(net)
+        edge_np = xc.out_edge_table(net)
         edge_slot = jnp.asarray(xc.in_edge_slots(edge_np, n,
                                                  pre_byk.shape[0]))
         n_slots = int(pre_byk.size)
@@ -234,25 +234,15 @@ def make_fap_vardt_runner(model: CellModel, net: Network, iinj, t_end: float,
             edge_tbl = edge_np
 
     def _horizon_of(tin, t_clock):
-        """The dependency horizon from the in-edge clock table: the same
-        adds and exact mins as the full scatter-min, so bitwise equal.
-        The min over K folds the table's K rows elementwise."""
-        cand = tin + delay_flat
-        hor = cand[:n]
-        for k in range(1, pre_byk.shape[0]):
-            hor = jnp.minimum(hor, cand[k * n:(k + 1) * n])
-        hor = jnp.minimum(hor, t_end)
-        return jnp.minimum(hor, t_clock + horizon_cap)
+        return xc.in_clock_horizon(tin, delay_flat, t_clock, t_end,
+                                   horizon_cap)
 
     def _push_clocks(tin, idc, moved, t_b):
         """Write each moved lane's clock into its out-edge slots of ``tin``
-        (each edge has one pre, so no two lanes write one slot); padding
-        and lanes that did not move point past the table and drop.
-        Returns the table and the number of slots written."""
+        (each edge has one pre, so no two lanes write one slot); lanes
+        that did not move point past the table."""
         slots = jnp.where(moved[:, None], edge_slot[idc], n_slots)
-        vals = jnp.broadcast_to(t_b[:, None], slots.shape)
-        return (tin.at[slots].set(vals, mode="drop"),
-                (slots < n_slots).sum(dtype=jnp.int64))
+        return xc.push_clocks(tin, slots, t_b)
 
     spike_ins = xc.make_spike_insert(net, dnet, qops, qinsert, fanout, s_cap,
                                      edge_table=edge_tbl)

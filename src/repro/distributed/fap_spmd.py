@@ -13,7 +13,11 @@ single-host execution models through ``exec_common`` and ``repro.sched``:
   horizon  — per-neuron dependency horizon + runnable mask:
              ``exec_common.horizon_times`` / ``runnable_mask`` (the same
              helper the single-host exec models call, here with the
-             shard-relative post index and the notify clock table)
+             shard-relative post index and the notify clock table); with
+             horizon="incremental" the moved clocks are pushed into a
+             shard-local in-edge clock table and the horizon is a dense
+             fold over it (``exec_common.in_clock_horizon``, as in
+             ``exec_fap``)
   advance  — per-neuron variable-order variable-step BDF to the horizon:
              ``exec_bsp.make_vardt_advance`` (unchanged, vmapped)
   parcels  — exchange (spiked, t_spike): ``Transport.exchange``
@@ -49,6 +53,7 @@ device-side cond), which ``run_fap_spmd`` and the benchmark drive.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -65,6 +70,14 @@ from repro.core.cell import CellModel
 from repro.core.exec_bsp import make_vardt_advance
 from repro.distributed.exchange import (ExchangeSpec, get_transport,
                                         shard_index)
+
+
+def _byk(x, k: int, n_shards: int = 1):
+    """An edge array of ``make_network``'s grouped by-post layout (local
+    edge ``j * k + kk`` of each shard's contiguous slice) in each shard's
+    by-k layout, the [k, n_local] table row-major: local edge ``j * k +
+    kk`` at ``kk * n_local + j``, shard after shard."""
+    return x.reshape(n_shards, -1, k).transpose(0, 2, 1).reshape(-1)
 
 
 class PaperNeuroSpec(NamedTuple):
@@ -120,16 +133,29 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     ``lax.cond`` (identical events, never a drop).  spike_cap <= 0 means
     min(N, 256).
 
-    horizon="incremental" extends PR 4's incremental horizon maintenance
-    to the shard-local round: the moved set is (a) last round's advanced
-    batch (carried compact ids) and (b) the *notify frontier* entries
-    whose gathered clock changed this round (compared against the carried
-    previous boundary-clock vector — ``sharding.shard_frontier`` tables),
-    and only rows fed by a moved clock recompute, bit-identical to the
-    full scatter-min because min is exact.  Requires optimized +
-    batch="compact" + a sparse-family transport (the frontier tables).
-    The round then carries (horizon, prev_boundary_clocks, moved_ids)
-    in ``SimCarry.hcarry``; ``make_fap_spmd_runner`` seeds them.
+    horizon="incremental" maintains the horizon shard-locally by pushing
+    clocks, as ``exec_fap``'s compact round does on one chip.  Each shard
+    carries an in-edge clock table: the [K, n_local] table of the clocks
+    of its in-edges' pres, by-k (local edge ``j * K + k`` at slot ``k *
+    n_local + j``) and stored row-major.  The moved set is (a) the
+    *notify frontier* entries of other shards whose gathered clock
+    changed this round (compared against the carried previous
+    boundary-clock vector — ``sharding.shard_frontier`` tables; at most
+    ``move_cap``, by default n_shards * batch_cap) and (b) the
+    shard's own lanes that advanced last round (carried compact ids).
+    Each moved clock is written into the slots of its out-edges (the
+    replicated ``exec_common.out_edge_table``) that land in the shard's
+    contiguous global-edge-id slice; the others, and the padding, drop.
+    The horizon is then one dense pass min_k(table + delay) clamped at
+    t_end and t + horizon_cap: bit-identical to the full scatter-min
+    because min is exact.  A moved set over ``move_cap`` (the round after
+    a seed, whose previous boundary clocks are zeros) rebuilds the table
+    whole from the notify table.  Requires optimized + batch="compact" +
+    a sparse-family transport (the frontier tables).  The round then
+    carries (in-edge clock table, prev_boundary_clocks, moved_ids) in
+    ``SimCarry.hcarry``; ``make_fap_spmd_runner`` seeds them.
+    ``SchedStats.horizon_live`` sums, over shards, the batch's lanes and
+    the slots the push wrote (all of them in a rebuild).
 
     Returns ``(fap_round, (carry, static), (carry_sh, static_sh))``:
     ``fap_round(carry, static) -> carry`` advances one round.  ``carry`` is
@@ -192,17 +218,13 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     tp = get_transport(transport, mesh, n=n, net=net, spec=exchange) \
         if optimized else None
     n_targs = len(tp.example_args) if tp is not None else 0
-    # replicated static tables (appended to the round args after targs);
-    # one host-side grouping pass serves both views
+    # replicated static table (appended to the round args after targs):
+    # the out-edge table serves the compact fan-out and the push
     tbl_args, tbl_specs = (), ()
     if fanout == "compact" or incremental:
-        post_np, edge_np = xc.out_tables(net)
-    if fanout == "compact":
-        tbl_args += (jnp.asarray(edge_np),)                # [N, MO], sent. E
+        tbl_args += (jnp.asarray(xc.out_edge_table(net)),)  # [N, MO], sent. E
         tbl_specs += (P(None, None),)
     if incremental:
-        tbl_args += (jnp.asarray(post_np),)                # [N, MOp], sent. N
-        tbl_specs += (P(None, None),)
         sf_len = int(np.prod(tp.example_args[1].shape))    # n_shards * F
         mcap = min(sf_len, n_shards * cap) if move_cap <= 0 \
             else min(int(move_cap), sf_len)
@@ -237,59 +259,64 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
         # --- notify: clock exchange (stepping notifications) --------------
         with jax.named_scope("fap.notify"):
             t_table, bnd_new = tp.notify(t_local, *targs)
-        # --- horizon + runnable (shared helper, shard-relative post) ------
-        post_rel = jnp.repeat(jnp.arange(n_loc), spec.k_in)
-        dloc = xc.DeviceNet(pre_l, post_rel, delay_l, wa_l, wg_l)
-
-        def _full_horizon(_):
-            return xc.horizon_times(dloc, n_loc, t_local, spec.t_end,
-                                    t_table=t_table,
-                                    horizon_cap=spec.horizon_cap)
-
+        # --- horizon + runnable -------------------------------------------
         if incremental:
-            hor_c, prev_bnd, moved_prev = carry
-            post_tbl_r = tbls[-1]
+            tin, prev_bnd, moved_prev = carry
+            edge_tbl_r = tbls[0]
             b_gid_flat = targs[1].reshape(-1)              # [n_shards * F]
             sf = b_gid_flat.shape[0]
-            pre_byk = pre_l.reshape(n_loc, spec.k_in).T    # [K, n_loc]
-            delay_byk = delay_l.reshape(n_loc, spec.k_in).T
+            k_in, n_slots = spec.k_in, spec.k_in * n_loc
 
-            def _rows(p):
-                """Recompute horizon rows ``p`` (sentinel-padded) from the
-                fresh notify table — the same min/clamp chain as the full
-                scatter-min (min is exact: incremental == full, bitwise)."""
-                pc = jnp.minimum(p, n_loc - 1)
-                cand = t_table[pre_byk[:, pc]] + delay_byk[:, pc]
-                h = jnp.minimum(jnp.min(cand, axis=0), spec.t_end)
-                return jnp.minimum(h, t_local[pc] + spec.horizon_cap)
-
-            def _incr_horizon(hor):
+            def _push(tin):
+                """Each moved clock into the slots of its out-edges that
+                land in this shard (by-k: local edge le = j * K + k sits at
+                k * n_loc + j); others, and padding, drop."""
                 own = jnp.where(moved_prev < n_loc, moved_prev + offset, n)
                 srcs = jnp.concatenate([gids, own])
-                posts = jnp.where((srcs < n)[:, None],
-                                  post_tbl_r[jnp.minimum(srcs, n - 1)], n)
-                p_loc = posts - offset
-                p_loc = jnp.where(
-                    jnp.logical_and(p_loc >= 0, p_loc < n_loc), p_loc, n_loc)
-                p = jnp.concatenate([moved_prev, p_loc.reshape(-1)])
-                return hor.at[p].set(_rows(p), mode="drop")
+                vals = jnp.concatenate(
+                    [bnd_new[jnp.minimum(mids, sf - 1)],
+                     t_local[jnp.minimum(moved_prev, n_loc - 1)]])
+                le = edge_tbl_r[jnp.minimum(srcs, n - 1)] - sidx * e_local
+                ok = jnp.logical_and((srcs < n)[:, None], jnp.logical_and(
+                    le >= 0, le < e_local))
+                slots = jnp.where(ok, (le % k_in) * n_loc + le // k_in,
+                                  n_slots)
+                return xc.push_clocks(tin, slots, vals)
+
+            def _rebuild(_):
+                """The whole table from the notify table (the moved set
+                overflowed ``move_cap``)."""
+                return (t_table[_byk(pre_l, k_in)],
+                        jnp.asarray(n_slots, jnp.int64))
 
             with jax.named_scope("fap.horizon_update"):
                 # moved set: frontier clocks that changed since last round
                 # (pad slots carry the gid sentinel n -> masked out) plus
-                # last round's locally advanced lanes (carried compact ids)
+                # last round's locally advanced lanes (carried compact ids);
+                # this shard's own frontier clocks come with its lanes
                 moved_b = jnp.logical_and(bnd_new != prev_bnd, b_gid_flat < n)
                 mids, mcnt = ew_ops.compact_ids(moved_b, mcap)
                 gids = jnp.where(mids < sf,
                                  b_gid_flat[jnp.minimum(mids, sf - 1)], n)
-                horizon = jax.lax.cond(mcnt <= mcap, _incr_horizon,
-                                       _full_horizon, hor_c)
+                gids = jnp.where(jnp.logical_and(gids >= offset,
+                                                 gids < offset + n_loc),
+                                 n, gids)
+                tin, pushed = jax.lax.cond(mcnt <= mcap, _push, _rebuild,
+                                           tin)
             prev_bnd = bnd_new
             with jax.named_scope("fap.horizon"):
+                horizon = xc.in_clock_horizon(
+                    tin, _byk(delay_l, k_in), t_local, spec.t_end,
+                    spec.horizon_cap)
                 runnable = xc.runnable_mask(t_local, horizon)
         else:
+            # the shared scatter-min with the shard-relative post index
+            post_rel = jnp.repeat(jnp.arange(n_loc), spec.k_in)
+            dloc = xc.DeviceNet(pre_l, post_rel, delay_l, wa_l, wg_l)
             with jax.named_scope("fap.horizon"):
-                horizon = _full_horizon(None)
+                horizon = xc.horizon_times(dloc, n_loc, t_local, spec.t_end,
+                                           t_table=t_table,
+                                           horizon_cap=spec.horizon_cap)
                 runnable = xc.runnable_mask(t_local, horizon)
         n_runnable = runnable.sum(dtype=jnp.int64)
         # --- advance (dense: all lanes; compact: the shard-local active
@@ -320,6 +347,9 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                     moved_prev = jnp.where(moved, ids, n_loc).astype(
                         jnp.int32)
             n_stepped = lane_ok.sum(dtype=jnp.int64)
+            if incremental:
+                # the batch's lanes and the table slots the push wrote
+                live = jax.lax.psum(n_stepped + pushed, flat)
         else:
             with jax.named_scope("fap.advance"):
                 sts, eq_t, spiked, t_sp, nd, nrs = vadvance(
@@ -375,12 +405,13 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
         out = (sts, eq.t, eq.w_ampa, eq.w_gaba, rec.times, rec.count, over,
                nd, nrs, dropped, pbytes, n_runnable, n_stepped)
         if incremental:
-            out += (horizon, prev_bnd, moved_prev)
+            out += (live, tin, prev_bnd, moved_prev)
         return out
 
-    # carried-extra specs: horizon [N] sharded, boundary-clock vector
-    # replicated (the all_gather output IS replicated), moved ids [S*cap]
-    # sharded (each shard's own compact batch)
+    # carried-extra specs: in-edge clock table [K * N] sharded (each
+    # shard's [K, n_local] by-k table), boundary-clock vector replicated
+    # (the all_gather output IS replicated), moved ids [S*cap] sharded
+    # (each shard's own compact batch)
     carry_specs = (P(flat), P(None), P(flat)) if incremental else ()
     # lanes dispatched a round, all shards together
     lanes = n_shards * cap if batch == "compact" else n
@@ -400,13 +431,16 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
                           P(flat), P(flat), P(flat), P(flat)) + carry_specs
                 + tp.in_specs + tbl_specs,
                 out_specs=(sts_specs, n2, n2, n2, n2, P(flat))
-                + (P(),) * 7 + carry_specs,
+                + (P(),) * 7 + ((P(),) if incremental else ())
+                + carry_specs,
                 check_vma=False)
             (sts, eq_t, eq_a, eq_g, rec_t, rec_n, over, nd, nrs, dropped,
              pbytes, n_runnable, n_stepped, *hc) = fn_l(
                 sc.sts, eq_t, eq_a, eq_g, rec.times, rec.count, pre, delay,
                 w_a, w_g, iinj, *sc.hcarry, *static[6:])
             rec = ev.SpikeRecord(rec_t, rec_n, rec.overflow + over)
+            if incremental:
+                live, *hc = hc
         else:
             t_clock = sc.sts.t
             dnet = xc.DeviceNet(pre, post, delay, w_a, w_g)
@@ -433,6 +467,8 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
         stats = st._replace(runnable=st.runnable + n_runnable,
                             stepped=st.stepped + n_stepped,
                             lanes=st.lanes + lanes, rounds=st.rounds + 1)
+        if incremental:
+            stats = stats._replace(horizon_live=st.horizon_live + live)
         return xc.SimCarry(sts, (eq_t, eq_a, eq_g), rec, tuple(hc), {
             "n_ev": c["n_ev"] + nd, "n_rs": c["n_rs"] + nrs,
             "dropped": c["dropped"] + dropped,
@@ -450,7 +486,7 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
             model, 0.0, model.init_state(), i, opts))(jnp.zeros((n,), f8)))
     hcarry = ()
     if incremental:
-        hcarry = (sds((n,), f8),                       # horizon
+        hcarry = (sds((E,), f8),                       # in-edge clocks
                   sds((sf_len,), f8),                  # prev boundary clocks
                   sds((n_shards * cap,), i4))          # moved ids
     carry = xc.SimCarry(
@@ -479,6 +515,7 @@ def build_fap_round(model: CellModel, spec: PaperNeuroSpec, mesh,
     static_sh = (rows,) * 6 + (tp.shardings if tp is not None else ()) \
         + tuple(named(s_) for s_ in tbl_specs)
     fap_round.batch_cap, fap_round.spike_cap = cap, s_cap    # caps in force
+    fap_round.move_cap = mcap if incremental else 0
     return fap_round, (carry, static), (carry_sh, static_sh)
 
 
@@ -508,8 +545,12 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
     recorded a neuron).  The round-invariant arrays (``run.static``) are
     placed on the mesh once; ``round_body`` closes over them and
     ``run.round_with(carry, static)`` takes them as arguments.
-    ``run.batch_cap`` / ``run.spike_cap`` are the caps in force,
-    ``run.n_local`` the neurons a shard holds."""
+    ``run.batch_cap`` / ``run.spike_cap`` / ``run.move_cap`` are the caps
+    in force, ``run.n_local`` the neurons a shard holds.  With
+    horizon="incremental", ``run.horizon_of(carry)`` is the horizon the
+    carry's in-edge clock table implies at the carry's clocks and
+    ``run.reseed(carry)`` seeds the horizon carry anew from the clocks
+    (what a resume onto another layout does)."""
     n = int(net.n)
     k = sched.grouped_k(net)
     if k is None:
@@ -536,16 +577,28 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
     n_local = n // n_shards
 
     def seed_hcarry(clocks, static):
-        """(horizon, prev boundary clocks, moved ids) seeded from a clock
-        vector: a full-recompute horizon (which the incremental chain
-        equals bitwise — min is exact), all-zero previous boundary clocks
-        (every frontier entry looks moved next round -> extra or full
-        recompute, still exact) and sentinel moved ids.  Round 0 and
-        elastic resume share this."""
-        hor0 = xc.horizon_times(xc.DeviceNet(*static[:5]), n, clocks, t_end,
-                                horizon_cap=horizon_cap)
-        return (hor0, jnp.zeros(ex.hcarry[1].shape, jnp.float64),
+        """(in-edge clock table, prev boundary clocks, moved ids) seeded
+        from a clock vector: every shard's table gathered whole, all-zero
+        previous boundary clocks (every frontier entry whose clock is not
+        0 looks moved next round -> a push or a rebuild of clocks the
+        table already holds, still exact) and sentinel moved ids.  Round 0
+        and elastic resume share this."""
+        return (clocks[_byk(static[0], k, n_shards)],
+                jnp.zeros(ex.hcarry[1].shape, jnp.float64),
                 jnp.full(ex.hcarry[2].shape, n_local, jnp.int32))
+
+    @jax.jit
+    def _horizon_of(tin, t_clock, delay):
+        fold = functools.partial(xc.in_clock_horizon, t_end=t_end,
+                                 horizon_cap=horizon_cap)
+        return jax.vmap(fold)(tin.reshape(n_shards, -1),
+                              _byk(delay, k, n_shards).reshape(n_shards, -1),
+                              t_clock.reshape(n_shards, -1)).reshape(-1)
+
+    def horizon_of(sc):
+        """The dependency horizon an incremental carry's in-edge clock
+        table implies at the carry's clocks."""
+        return _horizon_of(sc.hcarry[0], sc.sts.t, static[2])
 
     def _init(static):
         iv = static[5]
@@ -567,6 +620,10 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
     # type, and the first round would compile the round a second time
     jinit = jax.jit(_init, out_shardings=carry_sh)
     jseed = jax.jit(seed_hcarry, out_shardings=carry_sh.hcarry)
+
+    def reseed(sc):
+        """``sc`` with its horizon carry seeded anew from its clocks."""
+        return sc._replace(hcarry=jseed(sc.sts.t, static))
 
     def init_carry():
         return jinit(static)
@@ -595,16 +652,19 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
 
         def health_of(sc, t_prev):
             return xc.health_check(
-                sc.sts, t_prev, horizon=sc.hcarry[0] if incremental else None,
+                sc.sts, t_prev,
+                horizon=horizon_of(sc) if incremental else None,
                 horizon_cap=horizon_cap)
 
         # layout fingerprint: a resume whose mesh/transport layout changed
         # must reseed the shard-relative hcarry even when its widths
-        # coincide
+        # coincide, and so must a snapshot of the earlier carry, which held
+        # the horizon [N] itself (the same shape where k_in is 1)
         fingerprint = {"mesh_shape": [int(mesh.shape[a])
                                       for a in mesh.axis_names],
                        "transport": transport, "batch_cap": int(batch_cap),
-                       "horizon": horizon} if incremental else None
+                       "horizon": horizon,
+                       "hcarry": "in_edge_clocks"} if incremental else None
         sc, health = xc.run_checkpointed(
             init_carry, lambda sc: jround(sc, static),
             lambda sc: bool(jcond(sc)), ckpt_dir=ckpt_dir,
@@ -612,9 +672,7 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
             keep=ckpt_keep, fault=fault,
             health_of=health_of if watchdog else None,
             max_rollbacks=max_rollbacks, shardings=carry_sh,
-            fingerprint=fingerprint,
-            reseed=(lambda sc: sc._replace(hcarry=jseed(sc.sts.t, static)))
-            if incremental else None)
+            fingerprint=fingerprint, reseed=reseed if incremental else None)
         sts = sc.sts
         rounds = int(sc.counters["rounds"])
         health["dropped_events"] = int(sc.counters["dropped"])
@@ -640,7 +698,11 @@ def make_fap_spmd_runner(model: CellModel, net, iinj, t_end: float, mesh,
     run.pack = lambda sc: sc
     run.static = static
     run.batch_cap, run.spike_cap = fn.batch_cap, fn.spike_cap
+    run.move_cap = fn.move_cap
     run.n_local = n_local
+    if incremental:
+        run.horizon_of = horizon_of   # incremental carry -> its horizon
+        run.reseed = reseed
     return run
 
 
@@ -676,11 +738,12 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
     batch / batch_cap / fanout / spike_cap / horizon / move_cap: forwarded
     to ``build_fap_round`` — "compact" runs the shard-local advance
     (delivery) on the compacted runnable (spiking) set only, and
-    horizon="incremental" carries the dependency horizon across rounds
-    recomputing only frontier-fed rows.  ``RunResult.sched`` holds the
-    round's ``SchedStats`` (summed over shards); ``RunResult.comm`` the
-    parcel bytes each device shipped, summed over rounds — with the
-    ragged transport the per-round class choice made visible.
+    horizon="incremental" carries each shard's in-edge clock table across
+    rounds and pushes only the moved clocks into it.  ``RunResult.sched``
+    holds the round's ``SchedStats`` (summed over shards);
+    ``RunResult.comm`` the parcel bytes each device shipped, summed over
+    rounds — with the ragged transport the per-round class choice made
+    visible.
 
     Preemption tolerance (exec_common.run_checkpointed):
     checkpoint_every=k snapshots the full round state (``SimCarry``) into
@@ -688,8 +751,8 @@ def run_fap_spmd(model: CellModel, net, iinj, t_end: float, mesh,
     restarts from the latest complete checkpoint, event-for-event
     identical to the uninterrupted run.  Elastic resume onto a different
     mesh shape works transparently: only the mesh-shaped horizon-carry
-    leaves are reseeded (a full recompute the incremental scheme equals
-    bitwise), everything else restores through
+    leaves are reseeded from the clocks (a full rebuild, which the pushed
+    table equals bitwise), everything else restores through
     ``restore_checkpoint(shardings=)``.  fault: a
     ``checkpoint.FaultPlan`` for kill/poison injection; watchdog (on by
     default here) runs the per-round ``exec_common.health_check`` and

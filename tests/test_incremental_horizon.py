@@ -75,7 +75,7 @@ def test_pushed_table_and_horizon_bitwise(model, case):
     net = NETS["hub" if case == "hub" else "natural"]()
     if case == "hub":
         deg = np.bincount(np.asarray(net.pre), minlength=N)
-        width = xc.out_tables(net)[0].shape[1]
+        width = xc.out_edge_table(net).shape[1]
         assert deg.max() == width == 48 and np.sort(deg)[-2] < width
     iinj = _drive(N)
     t_end = 40.0
@@ -114,7 +114,7 @@ def test_in_edge_slots_map_the_out_edge_table():
     net = _hub_net(64, 24, seed=2)
     k = sched.grouped_k(net)
     pre_byk, _ = ew_ops.by_post_layout(net)
-    _, edge_t = xc.out_tables(net)
+    edge_t = xc.out_edge_table(net)
     slots = xc.in_edge_slots(edge_t, net.n, k)
     E = net.n * k
     real = edge_t < E
